@@ -2,9 +2,9 @@
 
 The paper evaluates single-threaded Java queries.  The TPU-native rethink
 batches Q query tokens across S segments: throughput here is probes/sec
-of the jnp oracle vs the Pallas kernel (interpret mode on CPU — on TPU
-the same call compiles natively; numbers are architecture-shape evidence,
-not TPU wall clock)."""
+of the jitted device lookup vs the numpy host lookup, and of the
+``bitmap_extract`` kernel (interpreted off the TPU, so off-chip numbers
+are architecture-shape evidence, not TPU wall clock)."""
 import time
 
 import numpy as np
@@ -17,7 +17,6 @@ def run(results: dict):
     from repro.core.batch_builder import build_sealed
     from repro.core.immutable_sketch import build_immutable
     from repro.core.mphf import build_mphf
-    from repro.kernels import mphf_probe
 
     rng = np.random.default_rng(0)
     n_tokens = 200_000
@@ -28,7 +27,7 @@ def run(results: dict):
     q = rng.integers(0, 2**32, 16384, dtype=np.uint64).astype(np.uint32)
     qj = jnp.asarray(q)
 
-    # jnp oracle probe (jit)
+    # device lookup (jit)
     probe_jnp = jax.jit(lambda f: mphf.lookup_jnp(f))
     probe_jnp(qj)[0].block_until_ready()
     t0 = time.perf_counter()
@@ -68,7 +67,7 @@ def run(results: dict):
     max_pop = int(np.unpackbits(bm.view(np.uint8), axis=1).sum(axis=1).max())
     mh = 1 << (max(max_pop, 1) - 1).bit_length()
     bmj = jnp.asarray(bm)
-    ext = jax.jit(lambda b: bitmap_extract(b, max_hits=mh, use_kernel=False)[0])
+    ext = jax.jit(lambda b: bitmap_extract(b, max_hits=mh)[0])
     ext(bmj).block_until_ready()
     t0 = time.perf_counter()
     for _ in range(iters):

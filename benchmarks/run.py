@@ -20,6 +20,8 @@ import json
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from . import (dedup_stats, disk_usage, error_rate, ingest_speed,
                live_tail, probe_bench, query_throughput, roofline,
                scan_rate, serve_load)
@@ -41,8 +43,9 @@ MODULES = {
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
-    ap.add_argument("--out", default="/root/repo/bench_results.json")
+    ap.add_argument("--out", default="bench_results.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     results: dict = {}
     t0 = time.time()
     for name, mod in MODULES.items():

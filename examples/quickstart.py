@@ -89,9 +89,9 @@ with tempfile.TemporaryDirectory() as tmp:
 # 9. serve it: store.serving() puts a wave-coalescing scheduler in front
 # of the engine — concurrent clients' queries group into shape-bucketed
 # waves (deadline- or size-flushed, max_live_waves admission control),
-# and a measured cost model picks the host or device path per wave.
-# Build one with `make bench-smoke-serve`, then pass
-# cost_model=CostModel.load() to use measured costs.
+# and every wave runs on the device.  A cost model measured with
+# `make bench-smoke-serve` (cost_model=CostModel.load()) may send small
+# waves to the scalar host path instead.
 seg_store = DynaWarpStore(batch_lines=128, mode="segmented",
                           memory_limit_bytes=1 << 16)
 seg_store.ingest(ds.lines)

@@ -31,7 +31,7 @@ Beyond-paper read-path knobs (PR 3, sharded device retrieval), also
     multi-pod production mesh) routes batched waves through the
     ``ShardedQueryEngine``: whole segments are assigned to mesh shards
     over every visible device, each shard probes its local segments
-    via ``shard_map`` with the same Pallas ``sketch_probe``/
+    via ``shard_map`` with the same MPHF probe +
     ``bitset_ops`` path, and per-shard partial bitmaps OR together —
     bit-identical to the single-device engine.  Per-shard segment
     buffers upload once and survive compaction rebuilds.

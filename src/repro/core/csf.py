@@ -21,6 +21,7 @@ from .bitio import BitWriter, np_peek_bits
 
 SAMPLE = 32          # prefix-sum sampling interval (configurable, §3.3)
 LEN_BITS = 5         # rank < 2^30 -> code length <= 31 -> 5-bit lengths
+_BLOCK_LEN_WORDS = SAMPLE * LEN_BITS // 32   # words of one block's lengths
 
 
 def code_length(rank: np.ndarray) -> np.ndarray:
@@ -73,12 +74,9 @@ class CompressedStaticFunction:
 
     # ---- device decode -----------------------------------------------------------
     def device_arrays(self) -> dict:
-        # n1 rides along so stacked/sharded probes can pass the clip bound
-        # as data (one traced decode body shared by every segment layout)
         return dict(bitseq=jnp.asarray(self.bitseq),
                     lengths=jnp.asarray(self.lengths),
-                    samples=jnp.asarray(self.samples.astype(np.int32)),
-                    n1=jnp.asarray(max(self.n - 1, 0), jnp.int32))
+                    samples=jnp.asarray(self.samples.astype(np.int32)))
 
     def get_jnp(self, idx, arrs=None):
         if arrs is None:
@@ -88,33 +86,30 @@ class CompressedStaticFunction:
 
 def csf_get_jnp(idx, arrs):
     """Decode ``idx`` against a :meth:`CompressedStaticFunction.device_arrays`
-    dict.  All bounds come from ``arrs`` (``n1`` = n - 1), so the same
-    traced body serves a single sketch and a stacked per-shard row."""
+    dict.  Nothing about the layout is static, so the same traced body
+    serves a single sketch and a stacked per-shard row.
+
+    A sample block's SAMPLE packed lengths fill whole words, so one
+    (N, _BLOCK_LEN_WORDS) gather fetches all of them and the prefix sum
+    runs on static bit fields of that tile."""
     bitseq, lengths, samples = arrs["bitseq"], arrs["lengths"], arrs["samples"]
-    n1 = arrs["n1"]
     idx = idx.astype(jnp.int32)
     block = idx // SAMPLE
-    base = block * SAMPLE
+    rel = idx - block * SAMPLE
+    cols = block[:, None] * _BLOCK_LEN_WORDS \
+        + jnp.arange(_BLOCK_LEN_WORDS, dtype=jnp.int32)
+    tile = lengths[jnp.minimum(cols, lengths.shape[0] - 1)]
     off = samples[block]
-    rel = idx - base
     nbits = jnp.zeros(idx.shape, dtype=jnp.int32)
     for j in range(SAMPLE):
-        lj = _jnp_peek(lengths,
-                       jnp.minimum(base + j, n1) * LEN_BITS,
-                       LEN_BITS).astype(jnp.int32)
+        w, sh = divmod(j * LEN_BITS, 32)
+        field = tile[:, w] >> jnp.uint32(sh)
+        if sh + LEN_BITS > 32:
+            field = field | (tile[:, w + 1] << jnp.uint32(32 - sh))
+        lj = (field & jnp.uint32((1 << LEN_BITS) - 1)).astype(jnp.int32)
         off = off + jnp.where(j < rel, lj, 0)
         nbits = jnp.where(j == rel, lj, nbits)
     return _jnp_peek_var(bitseq, off, nbits).astype(jnp.int32)
-
-
-def _jnp_peek(words, bitpos, nbits: int):
-    word = bitpos >> 5
-    off = (bitpos & 31).astype(jnp.uint32)
-    w0 = words[word]
-    w1 = words[jnp.minimum(word + 1, words.shape[0] - 1)]
-    lo = (w0 >> off)
-    hi = jnp.where(off > 0, w1 << (jnp.uint32(32) - off), jnp.uint32(0))
-    return (lo | hi) & jnp.uint32((1 << nbits) - 1)
 
 
 def _jnp_peek_var(words, bitpos, nbits):

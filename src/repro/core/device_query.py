@@ -5,9 +5,9 @@ The paper's Alg. 3 is a sequential host loop (probe token -> decode list
 bitmap algebra in ONE jit:  Q queries x T tokens probe the sketch
 (MPHF + signatures + CSF) -> each token resolves to its posting-plane
 row -> AND/OR across the token axis -> per-query candidate bitmaps +
-popcounts.  The bitset_ops Pallas kernel accelerates the plane
-reduction; everything stays in device memory, so a query wave over many
-segments is collective-free until the final candidate gather.
+popcounts.  The bitset_ops Pallas kernel folds the planes; everything
+stays in device memory, so a query wave over many segments is
+collective-free until the final candidate gather.
 
 Requires the immutable sketch to be built with bitmap planes
 (build_immutable(..., plane_budget_bytes=...)), which the paper's layout
@@ -20,31 +20,22 @@ import numpy as np
 import jax.numpy as jnp
 
 
-def batched_match_bitmaps(sketch, fps, arrs=None, *, use_kernel=False):
+def batched_match_bitmaps(sketch, fps, arrs=None):
     """fps (Q, T) int32/uint32 -> (Q, T, W) uint32 posting bitmaps
     (absent tokens give zero rows)."""
     q, t = fps.shape
-    rows = sketch.match_bitmap_jnp(jnp.asarray(fps).reshape(-1), arrs,
-                                   use_kernel=use_kernel)
+    rows = sketch.match_bitmap_jnp(jnp.asarray(fps).reshape(-1), arrs)
     return rows.reshape(q, t, -1)
 
 
-def batched_query(sketch, fps, *, op: str = "and", arrs=None,
-                  use_kernel=True):
+def batched_query(sketch, fps, *, op: str = "and", arrs=None):
     """Alg. 3 for a (Q, T) token batch in one jit.
 
     Returns (bitmaps (Q, W) uint32, counts (Q,) int32).  ``op='and'``:
-    batches containing every token of the query; ``'or'``: any token.
-    ``use_kernel=True`` routes the MPHF probe and the T-axis plane
-    reduction through the Pallas ``sketch_probe`` / ``bitset_ops``
-    kernels; ``False`` keeps the pure-jnp mirror (the oracle path)."""
-    planes = batched_match_bitmaps(sketch, fps, arrs,
-                                   use_kernel=use_kernel)   # (Q, T, W)
-    if use_kernel:
-        from ..kernels.bitset_ops.ops import bitset_reduce_batch
-        return bitset_reduce_batch(planes, op=op)
-    from ..kernels.bitset_ops.ref import bitset_reduce_batch_ref
-    return bitset_reduce_batch_ref(planes, op=op)
+    batches containing every token of the query; ``'or'``: any token."""
+    from ..kernels.bitset_ops.ops import bitset_reduce_batch
+    planes = batched_match_bitmaps(sketch, fps, arrs)       # (Q, T, W)
+    return bitset_reduce_batch(planes, op=op)
 
 
 def bitmap_to_postings(bitmap_row: np.ndarray, n_postings: int) -> np.ndarray:

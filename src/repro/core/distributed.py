@@ -17,7 +17,7 @@ sets.  Here that becomes segment parallelism over the mesh:
     zero-copy from the per-device rows via
     ``jax.make_array_from_single_device_arrays`` — and one
     ``shard_map`` evaluates the whole (Q, T) wave: every shard probes
-    its local segments with the SAME ``sketch_probe`` kernel path the
+    its local segments with the SAME probe code path the
     single-device engine uses (:func:`core.immutable_sketch.
     match_bitmap_from`) and OR-accumulates its local token planes,
   * the only cross-shard traffic is the final all-gather of per-shard
@@ -39,9 +39,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from ..jax_compat import shard_map
 from .immutable_sketch import match_bitmap_from
 from .query_engine import QueryEngine
 
@@ -52,7 +51,7 @@ _ROW_FILL = {
     "signatures": 0,
     "csf_bitseq": 0, "csf_lengths": 0, "csf_samples": 0,
 }
-_ROW_SCALARS = ("fb_count", "n_tokens1", "csf_n1", "n_lists1", "active")
+_ROW_SCALARS = ("fb_count", "n_tokens1", "n_lists1", "active")
 
 
 def _p2(n: int) -> int:
@@ -70,10 +69,14 @@ def _pad1(a: np.ndarray, size: int, fill=0) -> np.ndarray:
 
 def default_shard_mesh(shard_axes=("data",)):
     """A mesh over every visible device, named by ``shard_axes`` (extra
-    leading axes get size 1 — ``('pod', 'data')`` works on one host)."""
+    leading axes get size 1 — ``('pod', 'data')`` works on one host).
+    Its axes are ``Auto``: the wave's placement is spelled out by the
+    shard_map specs, and arrays leaving it carry no sharding in their
+    types into the single-device reduce and extract kernels."""
     n = len(jax.devices())
     shape = (1,) * (len(shard_axes) - 1) + (n,)
-    return jax.make_mesh(shape, tuple(shard_axes))
+    return jax.make_mesh(shape, tuple(shard_axes),
+                         axis_types=(AxisType.Auto,) * len(shard_axes))
 
 
 class ShardedQueryEngine(QueryEngine):
@@ -82,10 +85,9 @@ class ShardedQueryEngine(QueryEngine):
 
     def __init__(self, segments, *, mesh=None, shard_axes=("data",),
                  n_postings: int | None = None, lru_lists: int = 4096,
-                 bitset_kernel: bool | None = None,
                  extract_on_device: bool | None = None):
         super().__init__(segments, n_postings=n_postings,
-                         lru_lists=lru_lists, bitset_kernel=bitset_kernel,
+                         lru_lists=lru_lists,
                          extract_on_device=extract_on_device)
         self.shard_axes = tuple(shard_axes)
         if mesh is None:
@@ -140,7 +142,6 @@ class ShardedQueryEngine(QueryEngine):
                                   shard_axes=self.shard_axes,
                                   n_postings=self.n_postings,
                                   lru_lists=self._lru_cap,
-                                  bitset_kernel=self._use_bitset_kernel,
                                   extract_on_device=self._extract_on_device)
 
     # -------------------------------------------------------------- buckets
@@ -176,7 +177,6 @@ class ShardedQueryEngine(QueryEngine):
             "csf_bitseq": _pad1(c.bitseq, bs_p2),
             "csf_lengths": _pad1(c.lengths, ln_p2),
             "csf_samples": _pad1(c.samples.astype(np.int32), sm_p2),
-            "csf_n1": np.int32(max(c.n - 1, 0)),
             "planes": planes,
             "n_lists1": np.int32(max(seg.n_lists - 1, 0)),
             "active": np.int32(1),
@@ -198,7 +198,6 @@ class ShardedQueryEngine(QueryEngine):
             "csf_bitseq": np.zeros(bs_p2, np.uint32),
             "csf_lengths": np.zeros(ln_p2, np.uint32),
             "csf_samples": np.zeros(sm_p2, np.int32),
-            "csf_n1": np.int32(0),
             "planes": np.zeros((pl_p2, w), np.uint32),
             "n_lists1": np.int32(0),
             "active": np.int32(0),
@@ -311,32 +310,27 @@ class ShardedQueryEngine(QueryEngine):
                         acc = parts[k] if k == 0 else acc | parts[k]
                 return acc.reshape(q, t, out_w)
 
-            smapped = shard_map(
-                body, self.mesh,
+            smapped = jax.shard_map(
+                body, mesh=self.mesh,
                 in_specs=(P(None, None),
                           tuple(row_specs for _ in metas)),
-                out_specs=P(None, None, None))
+                out_specs=P(None, None, None), check_vma=False)
             fn = self._wave_fn_cached = jax.jit(smapped)
         return fn
-
-    def _extract(self, bitmaps, counts):
-        """The wave's combined bitmaps come out replicated across the
-        mesh; compact them on one device (its replica is already local)
-        instead of running the extraction redundantly on every shard."""
-        if self.n_shards > 1 and getattr(bitmaps, "sharding", None) is not None:
-            bitmaps = jax.device_put(bitmaps, self._shard_devices[0][0])
-        return super()._extract(bitmaps, counts)
 
     def _device_token_planes(self, fps_dev):
         """Sharded override of the engine's plane fan-out: one fused
         dispatch for the whole bucketed fleet instead of one per
-        segment."""
+        segment.  The merged planes come out replicated over the mesh;
+        the reduce and extract stages run once, on the first shard's
+        device, where a replica already sits."""
         if not self._buckets:
             return None
         bucket_arrs = [self._bucket_global(key, seg_ids)
                        for key, seg_ids in self._buckets]
         fn = self._wave_fn(bucket_arrs)
-        return fn(fps_dev, tuple(g for g, _ in bucket_arrs))
+        planes = fn(fps_dev, tuple(g for g, _ in bucket_arrs))
+        return jax.device_put(planes, self._shard_devices[0][0])
 
 
 def _row_n_words(level_word_offset: tuple) -> int:

@@ -7,7 +7,7 @@ Build pipeline (host):
 
 Query pipeline:
   * host   : Algorithm 3 via numpy (query.py)
-  * device : batched jnp / Pallas probe over the flat uint32 buffers,
+  * device : batched jnp probe over the flat uint32 buffers,
              plus optional dense bitmap planes for on-device boolean
              algebra across query tokens (TPU adaptation, DESIGN.md §3).
 """
@@ -238,37 +238,25 @@ class ImmutableSketch:
         return (tuple(int(x) for x in self.mphf.level_bits),
                 tuple(int(x) for x in self.mphf.level_word_offset))
 
-    def probe_fingerprints_jnp(self, fps, arrs=None, *, use_kernel=False):
-        """jnp oracle of the device probe (mirrors probe_fingerprints_np).
-        ``use_kernel=True`` routes the MPHF lookup through the Pallas
-        ``sketch_probe`` kernel instead of the pure-jnp mirror."""
+    def probe_fingerprints_jnp(self, fps, arrs=None):
+        """Device probe (:func:`probe_tokens_from`) of this sketch; mirrors
+        :meth:`probe_fingerprints_np`."""
         if arrs is None:
             arrs = self.device_arrays()
-        fps = fps.astype(jnp.uint32)
-        if use_kernel:
-            lb, lo = self._level_layout()
-            return probe_tokens_from(fps, arrs, level_bits=lb,
-                                     level_word_offset=lo,
-                                     sig_bits=self.sig_bits)
-        idx, absent = self.mphf.lookup_jnp(fps, arrs)
-        return _resolve_probe(fps, idx, absent, arrs, self.sig_bits)
+        lb, lo = self._level_layout()
+        return probe_tokens_from(fps, arrs, level_bits=lb,
+                                 level_word_offset=lo, sig_bits=self.sig_bits)
 
-    def match_bitmap_jnp(self, fps, arrs=None, *, use_kernel=False):
+    def match_bitmap_jnp(self, fps, arrs=None):
         """(Q, W) u32 posting bitmaps per query fingerprint; absent tokens
         yield all-zero rows.  Requires bitmap planes."""
         if self.planes is None:
             raise ValueError("bitmap planes were not built for this sketch")
         if arrs is None:
             arrs = self.device_arrays()
-        if use_kernel:
-            lb, lo = self._level_layout()
-            return match_bitmap_from(fps, arrs, level_bits=lb,
-                                     level_word_offset=lo,
-                                     sig_bits=self.sig_bits)
-        present, rank = self.probe_fingerprints_jnp(fps, arrs,
-                                                    use_kernel=False)
-        rows = arrs["planes"][jnp.clip(rank, 0, arrs["n_lists1"])]
-        return jnp.where(present[:, None], rows, jnp.uint32(0))
+        lb, lo = self._level_layout()
+        return match_bitmap_from(fps, arrs, level_bits=lb,
+                                 level_word_offset=lo, sig_bits=self.sig_bits)
 
 
 def _resolve_probe(fps, idx, absent, arrs, sig_bits: int):
@@ -290,15 +278,15 @@ def _resolve_probe(fps, idx, absent, arrs, sig_bits: int):
 
 def probe_tokens_from(fps, arrs, *, level_bits: tuple,
                       level_word_offset: tuple, sig_bits: int):
-    """THE device probe code path (Pallas MPHF kernel + signature check +
-    CSF rank), parameterized by an ``ImmutableSketch.device_arrays`` dict.
+    """THE device probe code path (MPHF lookup + signature check + CSF
+    rank), parameterized by an ``ImmutableSketch.device_arrays`` dict.
     The single-device engine passes a segment's own arrays; the sharded
     engine passes a zero-padded row sliced from a stacked per-shard buffer
     — both produce bit-identical (present, rank)."""
-    from ..kernels.sketch_probe.ops import mphf_probe_arrs
+    from .mphf import lookup_arrs
     fps = fps.astype(jnp.uint32)
-    idx, absent = mphf_probe_arrs(fps, arrs, level_bits=level_bits,
-                                  level_word_offset=level_word_offset)
+    idx, absent = lookup_arrs(fps, arrs, level_bits=level_bits,
+                              level_word_offset=level_word_offset)
     return _resolve_probe(fps, idx, absent, arrs, sig_bits)
 
 

@@ -10,9 +10,9 @@ The minimal hash of a key resolved at level L with bit position p is
 ``rank(bits, level_offset[L] + p)`` — the number of set bits before it in
 the concatenated level bit-vectors; fallback keys get the tail indices.
 
-Query (device, jnp + the Pallas `sketch_probe` kernel): a handful of
-gathers + popcounts over a flat u32 word array with a sampled rank
-directory — no deserialization, mirroring the paper's mmap layout.
+Query (device, :func:`lookup_arrs`): a handful of XLA gathers + popcounts
+over a flat u32 word array with a sampled rank directory — no
+deserialization, mirroring the paper's mmap layout.
 """
 from __future__ import annotations
 
@@ -151,51 +151,54 @@ class MPHF:
         )
 
     def lookup_jnp(self, fps, arrs=None):
-        """jnp mirror of :meth:`lookup_np` (oracle for the probe kernel)."""
-        from .hashing import seeded_hash32
+        """Device lookup (:func:`lookup_arrs`) over this MPHF's arrays."""
         if arrs is None:
             arrs = self.device_arrays()
-        words = arrs["words"]
-        fps = fps.astype(jnp.uint32)
-        idx = jnp.zeros(fps.shape, dtype=jnp.int32)
-        found = jnp.zeros(fps.shape, dtype=bool)
-        for lvl in range(self.n_levels):
-            m = int(self.level_bits[lvl])
-            if m == 0:
-                continue
-            pos = seeded_hash32(fps, _level_seed(lvl)) % jnp.uint32(m)
-            gbit = pos.astype(jnp.int32) + (int(self.level_word_offset[lvl]) << 5)
-            word = gbit >> 5
-            hit = ((words[word] >> (gbit & 31).astype(jnp.uint32)) & 1)
-            hit = hit.astype(bool) & ~found
-            rank = self._rank_jnp(gbit, arrs)
-            idx = jnp.where(hit, rank, idx)
-            found = found | hit
-        if self.fallback_fps.size:
-            fb_fps, fb_idx = arrs["fallback_fps"], arrs["fallback_idx"]
-            fpos = jnp.clip(jnp.searchsorted(fb_fps, fps), 0, fb_fps.size - 1)
-            fhit = (fb_fps[fpos] == fps) & ~found
-            idx = jnp.where(fhit, fb_idx[fpos], idx)
-            found = found | fhit
-        return idx, ~found
+        return lookup_arrs(
+            fps, arrs, level_bits=tuple(int(x) for x in self.level_bits),
+            level_word_offset=tuple(int(x) for x in self.level_word_offset))
 
-    def _rank_jnp(self, gbit, arrs):
-        words = arrs["words"]
-        block_rank = arrs["block_rank"]
-        word = gbit >> 5
-        block = word >> 3
-        r = block_rank[block].astype(jnp.int32)
-        base = block << 3
-        nw = words.shape[0]
-        for j in range(RANK_BLOCK_WORDS):
-            w = jnp.minimum(base + j, nw - 1)
-            wv = words[w]
-            pc = jax_popcount(wv).astype(jnp.int32)
-            part_mask = (jnp.uint32(1) << (gbit & 31).astype(jnp.uint32)) - jnp.uint32(1)
-            pc_part = jax_popcount(wv & part_mask).astype(jnp.int32)
-            r = r + jnp.where(base + j < word, pc, 0)
-            r = r + jnp.where(base + j == word, pc_part, 0)
-        return r
+
+def lookup_arrs(fps, arrs, *, level_bits: tuple, level_word_offset: tuple):
+    """THE device MPHF lookup, a jnp mirror of :meth:`MPHF.lookup_np`:
+    (idx int32, absent bool) for a batch of fingerprints.
+
+    Only the level layout is static; everything else comes from ``arrs``
+    (a :meth:`MPHF.device_arrays` dict, or a zero-padded row of a stacked
+    per-shard buffer).  Each level costs two XLA gathers over arrays in
+    HBM: the probed word's whole 8-word rank block, (N, 8) at once, and
+    its sampled block rank.  Fallback keys resolve against the sorted
+    ``fallback_fps``, guarded by the dynamic ``fb_count`` so padded or
+    fallback-less rows never match."""
+    from .hashing import seeded_hash32
+    words, block_rank = arrs["words"], arrs["block_rank"]
+    fps = jnp.asarray(fps).astype(jnp.uint32)
+    lane = jnp.arange(RANK_BLOCK_WORDS, dtype=jnp.int32)
+    idx = jnp.zeros(fps.shape, dtype=jnp.int32)
+    found = jnp.zeros(fps.shape, dtype=bool)
+    for lvl, m in enumerate(level_bits):
+        if m == 0:
+            continue
+        pos = seeded_hash32(fps, _level_seed(lvl)) % jnp.uint32(m)
+        gbit = pos.astype(jnp.int32) + (int(level_word_offset[lvl]) << 5)
+        word = (gbit >> 5)[:, None]
+        cols = ((word >> 3) << 3) + lane                         # (N, 8)
+        blk = words[jnp.minimum(cols, words.shape[0] - 1)]
+        wv = jnp.max(jnp.where(cols == word, blk, jnp.uint32(0)), axis=1)
+        bit = (gbit & 31).astype(jnp.uint32)
+        hit = ((wv >> bit) & 1).astype(bool) & ~found
+        before = jnp.where(cols < word, jax_popcount(blk), jnp.uint32(0))
+        rank = (block_rank[word[:, 0] >> 3].astype(jnp.int32)
+                + jnp.sum(before, axis=1).astype(jnp.int32)
+                + jax_popcount(wv & ((jnp.uint32(1) << bit) - jnp.uint32(1)))
+                .astype(jnp.int32))
+        idx = jnp.where(hit, rank, idx)
+        found = found | hit
+    fb_fps, fb_idx = arrs["fallback_fps"], arrs["fallback_idx"]
+    fpos = jnp.clip(jnp.searchsorted(fb_fps, fps), 0, fb_fps.shape[0] - 1)
+    fhit = (fb_fps[fpos] == fps) & (fpos < arrs["fb_count"]) & ~found
+    idx = jnp.where(fhit, fb_idx[fpos], idx)
+    return idx, ~(found | fhit)
 
 
 def jax_popcount(x):

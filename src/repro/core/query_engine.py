@@ -10,8 +10,8 @@ one reduce dispatch per wave:
   * **Shape-bucketed batching** — Q queries x T token fingerprints are
     packed into padded (Q_bucket, T_bucket) arrays (powers of two), so
     repeated waves hit one jit cache entry per bucket shape.  The MPHF
-    lookup runs through the Pallas ``sketch_probe`` kernel and the
-    T-axis boolean reduction through the Pallas ``bitset_ops`` kernel.
+    lookup is XLA gathers (``core.mphf.lookup_arrs``) and the T-axis
+    boolean reduction runs through the Pallas ``bitset_ops`` kernel.
   * **Multi-segment fan-out** — per-spill immutable segments stay
     queryable (no monolithic merge): each segment contributes per-token
     posting bitmaps, OR-ed across segments before the AND/OR consumer.
@@ -21,8 +21,8 @@ one reduce dispatch per wave:
     budget exceeded) are probed on the host, with an LRU cache of
     decoded BIC posting lists, and their bitmaps OR-ed into the wave.
   * **Device candidate extraction** — the combined hit bitmaps compact
-    into posting-id lists on device (Pallas ``bitmap_extract`` kernel /
-    jnp ref), so only a (Q, max_hits) id tensor crosses to host; the
+    into posting-id lists on device (Pallas ``bitmap_extract`` kernel),
+    so only a (Q, max_hits) id tensor crosses to host; the
     host-mode fallback decodes rows via an LRU-cached flatnonzero word
     decode instead of a full ``np.unpackbits`` bit matrix.
 
@@ -60,17 +60,9 @@ class QueryEngine:
     """Evaluates query waves against one or more immutable segments."""
 
     def __init__(self, segments, *, n_postings: int | None = None,
-                 lru_lists: int = 4096, bitset_kernel: bool | None = None,
+                 lru_lists: int = 4096,
                  extract_on_device: bool | None = None):
         self.segments = [s for s in segments if s.n_tokens > 0]
-        # The MPHF probe always runs through the Pallas sketch_probe
-        # kernel.  The T-axis fold uses the Pallas bitset kernel on real
-        # TPU backends; under CPU interpret mode every pallas_call pays
-        # a multi-ms interpreter tax, so the default there is the
-        # bit-identical jnp fold (the kernel's own oracle).
-        if bitset_kernel is None:
-            bitset_kernel = jax.default_backend() == "tpu"
-        self._use_bitset_kernel = bitset_kernel
         # Batched waves compact hit bitmaps into posting-id lists on
         # device (kernels/bitmap_extract): only the (Q, max_hits) id
         # tensor crosses to host.  ``False`` keeps extraction on the
@@ -189,7 +181,7 @@ class QueryEngine:
 
     def _seg_fn(self, si: int):
         """Jitted per-segment probe: (Qb, Tb) fps -> (Qb, Tb, W) token
-        bitmaps (Pallas MPHF probe + signature check + CSF rank + plane
+        bitmaps (MPHF lookup + signature check + CSF rank + plane
         gather), padded to the engine-global bitmap width."""
         fn = self._seg_fns.get(si)
         if fn is None:
@@ -199,8 +191,7 @@ class QueryEngine:
             def body(fps2d, arrs):
                 self.compile_count += 1          # runs once per trace
                 q, t = fps2d.shape
-                rows = seg.match_bitmap_jnp(fps2d.reshape(-1), arrs,
-                                            use_kernel=True)
+                rows = seg.match_bitmap_jnp(fps2d.reshape(-1), arrs)
                 rows = rows.reshape(q, t, -1)[:, :, :out_w]
                 pad = out_w - rows.shape[-1]
                 if pad > 0:
@@ -212,19 +203,16 @@ class QueryEngine:
         return fn
 
     def _reduce_fn(self, op: str):
-        """Jitted wave consumer: neutralize pad slots, fold the T axis,
-        popcount.  Uses the Pallas bitset kernel on TPU backends."""
+        """Jitted wave consumer: neutralize pad slots, fold the T axis
+        and popcount through the Pallas ``bitset_ops`` kernel."""
         fn = self._reduce_fns.get(op)
         if fn is None:
             def body(planes, mask):
+                from ..kernels.bitset_ops.ops import bitset_reduce_batch
                 self.compile_count += 1
                 neutral = jnp.uint32(0xFFFFFFFF if op == "and" else 0)
                 planes = jnp.where(mask[:, :, None], planes, neutral)
-                if self._use_bitset_kernel:
-                    from ..kernels.bitset_ops.ops import bitset_reduce_batch
-                    return bitset_reduce_batch(planes, op=op)
-                from ..kernels.bitset_ops.ref import bitset_reduce_batch_ref
-                return bitset_reduce_batch_ref(planes, op=op)
+                return bitset_reduce_batch(planes, op=op)
 
             fn = jax.jit(body)
             self._reduce_fns[op] = fn
@@ -341,7 +329,6 @@ class QueryEngine:
         replicas without cross-wave locking."""
         return QueryEngine(self.segments, n_postings=self.n_postings,
                            lru_lists=self._lru_cap,
-                           bitset_kernel=self._use_bitset_kernel,
                            extract_on_device=self._extract_on_device)
 
     # ------------------------------------------------------------- sizing

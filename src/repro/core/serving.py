@@ -1,7 +1,7 @@
 """Wave-coalescing query serving front end with admission control.
 
 The engine's fast path is the padded power-of-two ``(Q, T)`` wave
-through the Pallas ``sketch_probe``/``bitset_ops`` kernels — but the
+through the device probe and the Pallas ``bitset_ops`` kernel — but the
 store alone only answers one-shot ``query_term_batch`` calls, so
 nothing *forms* waves from independent clients.  This module is the
 saxml-``ServableMethod``-shaped serving layer that does:
@@ -25,7 +25,8 @@ saxml-``ServableMethod``-shaped serving layer that does:
     :class:`CostModel` (emitted by ``benchmarks/query_throughput.py``)
     decides per wave whether the scalar host path (cheap for lone
     stragglers) or one jitted device wave (amortized across users)
-    answers faster.
+    answers faster.  Without a measured model every wave goes to the
+    device.
   * **Engine replicas** — waves round-robin over engine replicas
     (cheap: :meth:`QueryEngine.clone` shares every per-segment device
     cache), each guarded by its own lock so concurrent waves overlap
@@ -84,16 +85,13 @@ class CostModel:
     dispatch cost at that bucket.  A wave of ``n`` queries goes to the
     host path iff ``n * host_us_per_query <= device_us_per_wave[b]``
     for its bucket ``b`` — lone stragglers keep taking the scalar path,
-    big waves amortize the dispatch.  The defaults are CPU-interpret-
-    shaped placeholders; ``benchmarks/query_throughput.py`` emits the
-    measured model (:func:`CostModel.load`).
+    big waves amortize the dispatch.  There are no built-in costs:
+    ``benchmarks/query_throughput.py`` measures them on the serving
+    device (:func:`CostModel.load`).
     """
 
-    def __init__(self, *, host_us_per_query: float = 150.0,
-                 device_us_per_wave: dict | None = None):
-        if device_us_per_wave is None:
-            device_us_per_wave = {8: 4_000.0, 16: 4_500.0, 32: 5_000.0,
-                                  64: 6_000.0, 128: 8_000.0, 256: 12_000.0}
+    def __init__(self, *, host_us_per_query: float,
+                 device_us_per_wave: dict):
         if not device_us_per_wave:
             raise ValueError("device_us_per_wave must not be empty")
         self.host_us_per_query = float(host_us_per_query)
@@ -223,7 +221,8 @@ class WaveScheduler:
         self.flush_deadline_s = float(flush_deadline_s)
         self.max_live_waves = max(int(max_live_waves), 1)
         self.max_pending = max(int(max_pending), 1)
-        self.cost_model = cost_model if cost_model is not None else CostModel()
+        # None: no measured costs, so every wave goes to the device
+        self.cost_model = cost_model
         self._cv = threading.Condition()
         # every engine replica gets its own lock: waves overlap across
         # replicas but never race one engine's jit caches / LRUs
@@ -291,6 +290,12 @@ class WaveScheduler:
     @property
     def n_replicas(self) -> int:
         return len(self._engines)
+
+    @property
+    def engines(self) -> list:
+        """The current engine replicas (a copy of the list)."""
+        with self._cv:
+            return list(self._engines)
 
     # --------------------------------------------------------------- clients
     def submit(self, tokens, *, op: str = "and") -> WaveTicket:
@@ -416,7 +421,8 @@ class WaveScheduler:
         tickets, (op, _tb), _reason = wave
         n = len(tickets)
         q_bucket = self._q_bucket(n)
-        use_host = self.cost_model.prefer_host(n, q_bucket)
+        use_host = (self.cost_model is not None
+                    and self.cost_model.prefer_host(n, q_bucket))
         try:
             with lock:
                 if use_host:

@@ -1,22 +1,27 @@
 """Pallas TPU kernel: on-device candidate extraction (bitmap -> ids).
 
-The last host-side stage of the device query path was expanding the
-combined (Q, W) hit bitmaps into posting ids via ``np.unpackbits`` —
-materializing a full (Q, 32*W) bit matrix on the host per wave.  This
-kernel compacts each query's bitmap into a padded id list on device, so
-only the final (Q, max_hits) int32 tensor crosses to the host.
+Compacts each query's (W,) hit bitmap into its ascending list of set-bit
+positions, so only the final (Q, max_hits) int32 tensor crosses to the
+host instead of a full (Q, 32*W) bit matrix.
 
-Per query row (grid over Q): a fori_loop walks the W words carrying the
-running hit count.  Each word expands into its 32 bit lanes; the lane
-prefix sum gives every set bit its compacted slot, and a (32, 32)
-select-matrix (cum-1 == slot, a VPU-friendly substitute for an in-word
-scatter) produces the 32 output values, stored at the running offset via
-one dynamic-slice store.  Slots past the word's popcount are junk that
-the next word's store (or the ops-level count mask) overwrites.
+Grid: (row blocks of 8 queries, slot blocks of ``block_s`` output
+slots).  Every step works on whole (8, 128)-aligned tiles with no
+dynamic scalar reads, no gathers and no unaligned stores.  For each of
+its 8 rows it computes, with word index on sublanes and output slot on
+lanes:
 
-The output ref is ``max_hits + 32`` wide so the final word's full-vector
-store never lands out of bounds; ops.py slices the pad off and masks the
-tail with -1.
+  * the row's words and their inclusive popcount prefix as (Wp, 1)
+    columns — masked lane reductions of the (1, Wp) row over a (Wp, Wp)
+    triangle, which also transposes the row without a transpose op;
+  * for each slot s, the word holding the (s+1)-th set bit: the number
+    of words whose inclusive prefix is <= s (a sublane count);
+  * that word's value and the bits before it, picked by a one-hot
+    sublane reduction;
+  * the bit lane inside the word by a 5-step binary select on
+    popcounts of its low bits.
+
+Slots at or past the row's popcount hold -1.  The bitmap arrives as
+int32 (the same bits), since Mosaic reduces no unsigned integers.
 """
 from __future__ import annotations
 
@@ -25,44 +30,66 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..backend import interpret_mode
+
+ROWS = 8                # queries per grid step (one sublane tile)
+DEFAULT_BLOCK_S = 512   # output slots per grid step
 
 
-def _extract_kernel(bm_ref, out_ref, cnt_ref, *, n_words: int,
-                    max_hits: int):
-    row = bm_ref[...]                                    # (1, W) uint32
-    lane = jax.lax.broadcasted_iota(jnp.uint32, (1, 32), 1)
-    slot = jax.lax.broadcasted_iota(jnp.int32, (32, 32), 0)
+def _extract_kernel(bm_ref, out_ref, *, block_s: int):
+    wp = bm_ref.shape[1]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (wp, wp), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (wp, wp), 1)
+    word_ids = jax.lax.broadcasted_iota(jnp.int32, (wp, block_s), 0)
+    slot = pl.program_id(1) * block_s \
+        + jax.lax.broadcasted_iota(jnp.int32, (1, block_s), 1)
+    for r in range(ROWS):
+        row = bm_ref[r:r + 1, :]                                 # (1, Wp)
+        pc = jax.lax.population_count(row)
+        word_col = jnp.sum(jnp.where(sub == lane, row, 0),
+                           axis=1, keepdims=True)                # (Wp, 1)
+        incl_col = jnp.sum(jnp.where(lane <= sub, pc, 0),
+                           axis=1, keepdims=True)                # (Wp, 1)
+        excl_col = incl_col - jax.lax.population_count(word_col)
+        total = jnp.sum(pc, axis=1, keepdims=True)               # (1, 1)
+        # word of each slot: how many words end at or before it
+        word = jnp.sum((incl_col <= slot).astype(jnp.int32),
+                       axis=0, keepdims=True)                    # (1, bs)
+        hot = word_ids == word                                   # (Wp, bs)
+        wv = jnp.sum(jnp.where(hot, word_col, 0), axis=0, keepdims=True)
+        rank = slot - jnp.sum(jnp.where(hot, excl_col, 0),
+                              axis=0, keepdims=True)             # in-word
+        bit = jnp.zeros_like(rank)
+        for b in (16, 8, 4, 2, 1):
+            low = jax.lax.shift_right_logical(wv, bit) & ((1 << b) - 1)
+            cnt = jax.lax.population_count(low)
+            up = cnt <= rank
+            rank = rank - jnp.where(up, cnt, 0)
+            bit = bit + jnp.where(up, b, 0)
+        out_ref[r:r + 1, :] = jnp.where(slot < total, word * 32 + bit, -1)
 
-    def body(wi, cnt):
-        wv = row[0, wi]
-        bits = ((wv >> lane) & jnp.uint32(1)).astype(jnp.int32)  # (1, 32)
-        cum = jnp.cumsum(bits, axis=1)                           # inclusive
-        ids = (jnp.int32(32) * wi + lane.astype(jnp.int32))      # (1, 32)
-        # select[s, l]: lane l is this word's (s+1)-th set bit
-        select = ((cum - 1) == slot) & (bits == 1)               # (32, 32)
-        vals = jnp.sum(jnp.where(select, ids, 0),
-                       axis=1, dtype=jnp.int32)                  # (32,)
-        off = jnp.minimum(cnt, max_hits)
-        out_ref[0, pl.ds(off, 32)] = vals
-        return cnt + jnp.sum(bits)
 
-    cnt = jax.lax.fori_loop(0, n_words, body, jnp.int32(0))
-    cnt_ref[0, 0] = cnt
-
-
-@functools.partial(jax.jit, static_argnames=("max_hits", "interpret"))
-def bitmap_extract_pallas(bitmaps, *, max_hits: int, interpret: bool = True):
-    """bitmaps (Q, W) uint32 -> (ids (Q, max_hits + 32) int32 with junk
-    past each row's count, counts (Q,) int32)."""
-    q, w = bitmaps.shape
-    ids, counts = pl.pallas_call(
-        functools.partial(_extract_kernel, n_words=w, max_hits=max_hits),
-        grid=(q,),
-        in_specs=[pl.BlockSpec((1, w), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, max_hits + 32), lambda i: (i, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((q, max_hits + 32), jnp.int32),
-                   jax.ShapeDtypeStruct((q, 1), jnp.int32)],
-        interpret=interpret,
+@functools.partial(jax.jit, static_argnames=("n_slots", "block_s",
+                                             "interpret"))
+def bitmap_extract_pallas(bitmaps, *, n_slots: int,
+                          block_s: int = DEFAULT_BLOCK_S,
+                          interpret: bool | None = None):
+    """bitmaps (Q, Wp) int32, Q a multiple of 8 and Wp of 128 ->
+    (Q, n_slots) int32 set-bit positions, -1 past each row's count.
+    ``n_slots`` must be a multiple of ``block_s``, and ``block_s`` of 128
+    (ops.py pads)."""
+    q, wp = bitmaps.shape
+    assert q % ROWS == 0 and wp % 128 == 0
+    assert block_s % 128 == 0 and n_slots % block_s == 0
+    return pl.pallas_call(
+        functools.partial(_extract_kernel, block_s=block_s),
+        grid=(q // ROWS, n_slots // block_s),
+        in_specs=[pl.BlockSpec((ROWS, wp), lambda i, j: (i, 0))],
+        out_specs=pl.BlockSpec((ROWS, block_s), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((q, n_slots), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret_mode(interpret),
     )(bitmaps)
-    return ids, counts[:, 0]

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..backend import interpret_mode
 from ...baselines.csc import _seed
 from ...core.hashing import _FM32_1, _FM32_2
 
@@ -56,7 +57,7 @@ def _csc_kernel(fps_ref, bits_ref, out_ref, *, m: int, k: int, p: int,
                                              "interpret"))
 def csc_probe_pallas(fps, bits, *, m: int, k: int, p: int, j: int,
                      block_q: int = DEFAULT_BLOCK_Q,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """fps (Q,) uint32; bits (j, m/32) uint32 -> (Q, p) int32 partition
     survival mask."""
     q = fps.shape[0]
@@ -69,6 +70,6 @@ def csc_probe_pallas(fps, bits, *, m: int, k: int, p: int, j: int,
                   pl.BlockSpec(bits.shape, lambda i: (0, 0))],
         out_specs=pl.BlockSpec((block_q, p), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((q, p), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(fps[:, None], bits)
     return out

@@ -1,15 +1,10 @@
 """Public wrapper for the CSC probe kernel."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from .kernel import DEFAULT_BLOCK_Q, csc_probe_pallas
 from .ref import csc_probe_ref  # noqa: F401
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def csc_partition_mask(sketch, fps, *, block_q: int = DEFAULT_BLOCK_Q):
@@ -22,5 +17,5 @@ def csc_partition_mask(sketch, fps, *, block_q: int = DEFAULT_BLOCK_Q):
         fps = jnp.pad(fps, (0, pad))
     out = csc_probe_pallas(fps, jnp.asarray(sketch.bits), m=sketch.m,
                            k=sketch.k, p=sketch.p, j=sketch.j,
-                           block_q=block_q, interpret=_interpret())
+                           block_q=block_q)
     return out[:q].astype(bool)

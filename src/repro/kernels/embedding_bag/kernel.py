@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..backend import interpret_mode
+
 
 def _ebag_kernel(idx_ref, table_ref, out_ref):
     j = pl.program_id(1)
@@ -33,7 +35,7 @@ def _ebag_kernel(idx_ref, table_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def embedding_bag_pallas(table, idx, *, interpret: bool = True):
+def embedding_bag_pallas(table, idx, *, interpret: bool | None = None):
     """table (V, D) f32; idx (B, BAG) int32 -> (B, D) f32 bag sums."""
     b, bag = idx.shape
     v, d = table.shape
@@ -50,6 +52,6 @@ def embedding_bag_pallas(table, idx, *, interpret: bool = True):
             out_specs=pl.BlockSpec((1, d), lambda i, j, idx_p: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((b, d), table.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(idx, table)
     return out

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..backend import interpret_mode
+
 DEFAULT_BLOCK_S = 512
 NEG_INF = -2.0e38
 
@@ -67,7 +69,7 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref,
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def flash_decode_pallas(q, k_cache, v_cache, cache_len, *,
                         block_s: int = DEFAULT_BLOCK_S,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """q (B, Hq, D); k_cache/v_cache (B, S, Hkv, D); cache_len () int32.
     Returns (B, Hq, D) attention output.  S must be a block_s multiple
     (ops.py pads with masked positions)."""
@@ -100,6 +102,6 @@ def flash_decode_pallas(q, k_cache, v_cache, cache_len, *,
             jax.ShapeDtypeStruct((b, hq, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hq, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(jnp.atleast_1d(cache_len).astype(jnp.int32), q, k_cache, v_cache)
     return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)

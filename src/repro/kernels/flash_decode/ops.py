@@ -1,16 +1,11 @@
 """Public wrapper: pads the cache to the block size (padded positions are
-masked via cache_len) and dispatches interpret mode off-TPU."""
+masked via cache_len)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from .kernel import DEFAULT_BLOCK_S, flash_decode_pallas
 from .ref import flash_decode_ref  # noqa: F401
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def flash_decode(q, k_cache, v_cache, cache_len, *,
@@ -24,4 +19,4 @@ def flash_decode(q, k_cache, v_cache, cache_len, *,
         k_cache = jnp.pad(k_cache, cfg)
         v_cache = jnp.pad(v_cache, cfg)
     return flash_decode_pallas(q, k_cache, v_cache, cache_len,
-                               block_s=block_s, interpret=_interpret())
+                               block_s=block_s)

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..backend import interpret_mode
+
 DEFAULT_BLOCK_C = 2048
 
 
@@ -30,7 +32,7 @@ def _score_kernel(corpus_ref, query_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
 def retrieval_score_pallas(corpus, query, *,
                            block_c: int = DEFAULT_BLOCK_C,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """corpus (C, D), query (1, D) -> scores (C, 1)."""
     c, d = corpus.shape
     assert c % block_c == 0
@@ -42,6 +44,6 @@ def retrieval_score_pallas(corpus, query, *,
                   pl.BlockSpec((1, d), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((block_c, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((c, 1), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(corpus, query)
     return out

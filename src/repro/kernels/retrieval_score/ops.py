@@ -8,10 +8,6 @@ from .kernel import DEFAULT_BLOCK_C, retrieval_score_pallas
 from .ref import retrieval_score_ref  # noqa: F401
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def retrieval_scores(corpus, query, *, block_c: int = DEFAULT_BLOCK_C):
     """corpus (C, D), query (D,) -> (C,) scores."""
     c, d = corpus.shape
@@ -20,8 +16,7 @@ def retrieval_scores(corpus, query, *, block_c: int = DEFAULT_BLOCK_C):
     if pad:
         corpus = jnp.pad(corpus, ((0, pad), (0, 0)))
     out = retrieval_score_pallas(corpus, query[None].astype(corpus.dtype),
-                                 block_c=block_c,
-                                 interpret=_interpret())
+                                 block_c=block_c)
     return out[:c, 0]
 
 

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..backend import interpret_mode
 from ...core.hashing import POLY_M32, POLY_SEED, _FM32_1, _FM32_2
 
 DEFAULT_BLOCK_N = 1024
@@ -47,7 +48,7 @@ def _token_hash_kernel(bytes_ref, len_ref, out_ref, *, max_len: int,
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def token_hash_pallas(tokens_u8, lengths, *, block_n: int = DEFAULT_BLOCK_N,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """tokens_u8 (N, L) uint8 zero-padded; lengths (N,) int32.
     Returns (N,) uint32 fingerprints.  N must be a block_n multiple
     (ops.py pads)."""
@@ -64,6 +65,6 @@ def token_hash_pallas(tokens_u8, lengths, *, block_n: int = DEFAULT_BLOCK_N,
         ],
         out_specs=pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.uint32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(tokens_u8.astype(jnp.int32), lengths.astype(jnp.int32)[:, None])
     return out[:, 0]

@@ -1,17 +1,11 @@
-"""jit'd public wrapper: pads to the block size, picks interpret mode off
-the backend (CPU containers validate the kernel body in interpret mode;
-on TPU the same pallas_call compiles natively)."""
+"""Public wrapper: pads to the block size (the kernel compiles natively
+on a TPU and is interpreted elsewhere, see ``kernels.backend``)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from .kernel import DEFAULT_BLOCK_N, token_hash_pallas
 from .ref import token_hash_ref  # noqa: F401  (re-export for tests)
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def token_fingerprints(tokens_u8, lengths, *, block_n: int = DEFAULT_BLOCK_N):
@@ -22,6 +16,5 @@ def token_fingerprints(tokens_u8, lengths, *, block_n: int = DEFAULT_BLOCK_N):
     if pad:
         tokens_u8 = jnp.pad(tokens_u8, ((0, pad), (0, 0)))
         lengths = jnp.pad(lengths, (0, pad))
-    out = token_hash_pallas(tokens_u8, lengths, block_n=block_n,
-                            interpret=_interpret())
+    out = token_hash_pallas(tokens_u8, lengths, block_n=block_n)
     return out[:n]

@@ -97,11 +97,9 @@ def roofline_terms(per_dev_flops, per_dev_bytes, wire_bytes, n_chips,
 def _lower_compile(spec, shape_name, mesh):
     from .steps import build_bundle
     bundle = build_bundle(spec, shape_name, mesh)
-    # `with mesh` enters the legacy mesh context; set_mesh additionally
-    # sets the sharding context that shard_map/with_sharding_constraint
-    # resolve axis names against (no-op on older JAX).
-    from ..jax_compat import set_mesh
-    with mesh, set_mesh(mesh):
+    # set_mesh installs the sharding context that shard_map and
+    # with_sharding_constraint resolve axis names against
+    with jax.set_mesh(mesh):
         jitted = jax.jit(bundle.fn,
                          in_shardings=bundle.in_shardings,
                          out_shardings=bundle.out_shardings,

@@ -112,6 +112,8 @@ def main(argv=None):
     ap.add_argument("--cost-model", default=None,
                     help="bench_costmodel.json from query_throughput")
     args = ap.parse_args(argv)
+    from ..compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.arch in ("dynawarp", "copr"):
         if args.requests == 8:          # store default differs from LM

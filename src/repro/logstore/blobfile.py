@@ -96,10 +96,12 @@ class BlobFile:
                 fsync_dir(os.path.dirname(os.path.abspath(self.path)))
                 self._dir_synced = True
 
-    def close(self) -> None:
+    def close(self, _os_close=os.close) -> None:
+        # ``os.close`` is bound at definition time: a file collected at
+        # interpreter exit may find this module's globals already torn down
         fd = getattr(self, "_fd", None)
         if fd is not None:
-            os.close(fd)
+            _os_close(fd)
             self._fd = None
 
     def __del__(self):  # pragma: no cover - GC-timing dependent

@@ -5,20 +5,32 @@ zstandard is optional: containers without it fall back to stdlib zlib
 with a 1-byte header so the codecs can coexist; zlib-tagged blobs are
 readable everywhere, zstd-tagged blobs need zstandard installed (a
 clear RuntimeError says so).
+
+A zstd (de)compression context must not be used by two threads at once
+(concurrent serving readers decompress candidate batches in parallel), so
+every thread keeps its own.
 """
 from __future__ import annotations
 
+import threading
 import zlib
 
 try:
     import zstandard as zstd
-    _CCTX = zstd.ZstdCompressor(level=3)
-    _DCTX = zstd.ZstdDecompressor()
     HAVE_ZSTD = True
 except ImportError:  # pragma: no cover - depends on container
     zstd = None
-    _CCTX = _DCTX = None
     HAVE_ZSTD = False
+
+_LOCAL = threading.local()
+
+
+def _ctx(name: str, make):
+    ctx = getattr(_LOCAL, name, None)
+    if ctx is None:
+        ctx = make()
+        setattr(_LOCAL, name, ctx)
+    return ctx
 
 _TAG_ZSTD = b"z"
 _TAG_ZLIB = b"d"
@@ -27,7 +39,8 @@ _TAG_ZLIB = b"d"
 def compress_batch(lines: list[str]) -> bytes:
     raw = "\n".join(lines).encode("utf-8")
     if HAVE_ZSTD:
-        return _TAG_ZSTD + _CCTX.compress(raw)
+        cctx = _ctx("cctx", lambda: zstd.ZstdCompressor(level=3))
+        return _TAG_ZSTD + cctx.compress(raw)
     return _TAG_ZLIB + zlib.compress(raw, 6)
 
 
@@ -39,5 +52,6 @@ def decompress_batch(blob: bytes) -> list[str]:
         if not HAVE_ZSTD:
             raise RuntimeError(
                 "this store was written with zstandard; install it to read")
-        raw = _DCTX.decompress(payload if tag == _TAG_ZSTD else blob)
+        dctx = _ctx("dctx", zstd.ZstdDecompressor)
+        raw = dctx.decompress(payload if tag == _TAG_ZSTD else blob)
     return raw.decode("utf-8").split("\n")

@@ -164,8 +164,7 @@ def seq_parallel_attention(q, k, v, *, batch_axes, model_axis,
             attn_softcap=attn_softcap, q_chunk=q_chunk, kv_chunk=kv_chunk,
             q_offset=idx * q_loc.shape[1])
 
-    from ..jax_compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         inner,
         in_specs=(P(batch_axes, model_axis, None, None),
                   P(batch_axes, None, None, None),

@@ -168,8 +168,7 @@ def moe_ffn_sharded(x, w, *, n_experts: int, top_k: int,
         out = jax.lax.psum(out.astype(xs.dtype), expert_axis)
         return out, aux
 
-    from ..jax_compat import shard_map
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         inner,
         in_specs=(xp, P(None, None), wg_spec, wg_spec, wd_spec),
         out_specs=(xp, P()),

@@ -229,9 +229,8 @@ def _norm_sharded(cfg: LMConfig, x, w):
 
     if w is None or nonparam:
         w = jnp.zeros((d,), x.dtype)
-    from ..jax_compat import shard_map
-    return shard_map(inner, in_specs=(spec, P(ma)), out_specs=spec,
-                     check_vma=False)(x, w)
+    return jax.shard_map(inner, in_specs=(spec, P(ma)), out_specs=spec,
+                         check_vma=False)(x, w)
 
 
 def _constrain_act(cfg: LMConfig, x):

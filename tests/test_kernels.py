@@ -1,4 +1,4 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret mode on CPU) vs the
+"""Per-kernel shape/dtype sweeps: Pallas (interpreted off the TPU) vs the
 pure-jnp oracles in each kernel's ref.py."""
 import numpy as np
 import pytest
@@ -9,7 +9,7 @@ import jax.numpy as jnp
 
 from repro.kernels import (bitmap_extract, bitset_reduce,
                            bitset_reduce_batch, csc_partition_mask,
-                           embedding_bag_sum, mphf_probe, retrieval_scores,
+                           embedding_bag_sum, retrieval_scores,
                            token_fingerprints)
 from repro.kernels.bitmap_extract.ref import bitmap_extract_ref
 from repro.kernels.bitset_ops.ref import (bitset_reduce_batch_ref,
@@ -54,14 +54,14 @@ def test_bitset_batch_shapes(q, t, w, op, rng):
 
 
 @pytest.mark.parametrize("q,w,max_hits", [(1, 1, 8), (8, 4, 16),
-                                          (16, 33, 64), (5, 7, 4)])
+                                          (16, 33, 64), (5, 7, 4),
+                                          (9, 200, 700)])
 def test_bitmap_extract_shapes(q, w, max_hits, rng):
     """Kernel vs jnp ref vs a numpy oracle, including the truncation
     path (max_hits smaller than a row's popcount)."""
     bm = rng.integers(0, 2**32, (q, w), dtype=np.uint64).astype(np.uint32)
     bm[0] = 0                                   # an empty row
-    k_ids, k_cnt = bitmap_extract(jnp.asarray(bm), max_hits=max_hits,
-                                  use_kernel=True)
+    k_ids, k_cnt = bitmap_extract(jnp.asarray(bm), max_hits=max_hits)
     r_ids, r_cnt = bitmap_extract_ref(jnp.asarray(bm), max_hits=max_hits)
     np.testing.assert_array_equal(np.asarray(k_ids), np.asarray(r_ids))
     np.testing.assert_array_equal(np.asarray(k_cnt), np.asarray(r_cnt))
@@ -75,19 +75,26 @@ def test_bitmap_extract_shapes(q, w, max_hits, rng):
 
 @pytest.mark.parametrize("nkeys", [50, 1000, 20000])
 def test_mphf_probe_sweep(nkeys, rng):
-    from repro.core.mphf import build_mphf
+    """The device MPHF lookup (jitted, as the engine runs it) against
+    the host numpy lookup: every construction key resolves to the same
+    minimal hash, and the absent flags agree on random probes."""
+    from repro.core.mphf import build_mphf, lookup_arrs
     keys = np.unique(rng.integers(0, 2**32, nkeys, dtype=np.uint64)
                      .astype(np.uint32))
     m = build_mphf(keys)
     q = np.concatenate([keys, rng.integers(0, 2**32, 777, dtype=np.uint64)
                         .astype(np.uint32)])
-    ki, ka = mphf_probe(m, q)
-    ri, ra = m.lookup_jnp(jnp.asarray(q))
-    np.testing.assert_array_equal(np.asarray(ka),
-                                  np.asarray(ra).astype(bool))
-    keep = ~np.asarray(ka)
-    np.testing.assert_array_equal(np.asarray(ki)[keep],
-                                  np.asarray(ri)[keep])
+    layout = dict(level_bits=tuple(int(x) for x in m.level_bits),
+                  level_word_offset=tuple(int(x)
+                                          for x in m.level_word_offset))
+    ki, ka = jax.jit(lambda f, a: lookup_arrs(f, a, **layout))(
+        jnp.asarray(q), m.device_arrays())
+    ri, ra = m.lookup_np(q)
+    np.testing.assert_array_equal(np.asarray(ka), ra)
+    keep = ~ra
+    np.testing.assert_array_equal(np.asarray(ki)[keep], ri[keep])
+    np.testing.assert_array_equal(np.sort(np.asarray(ki)[:keys.size]),
+                                  np.arange(keys.size))
 
 
 @pytest.mark.parametrize("m_bits,k,p,j", [(1 << 12, 2, 16, 1),

@@ -10,7 +10,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro import jax_compat
 from repro.configs import ARCHS, get_arch
 from repro.launch.steps import family_init, family_loss
 
@@ -68,7 +67,7 @@ def test_moe_sharded_equals_global_on_unit_mesh():
     y_ref, aux_ref = moe_ffn(x, w, n_experts=e, top_k=2,
                              capacity_factor=8.0)  # no drops
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with mesh, jax_compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_sm, aux_sm = jax.jit(lambda x, w: moe_ffn_sharded(
             x, w, n_experts=e, top_k=2, capacity_factor=8.0,
             batch_axes=("data",), expert_axis="model",
@@ -198,7 +197,7 @@ def test_seq_parallel_attention_equivalence():
     want = blockwise_attention(q, k, v, causal=True, q_chunk=16,
                                kv_chunk=16)
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with mesh, jax_compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(lambda q, k, v: seq_parallel_attention(
             q, k, v, batch_axes=("data",), model_axis="model",
             causal=True, q_chunk=16, kv_chunk=16))(q, k, v)

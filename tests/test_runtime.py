@@ -82,9 +82,8 @@ def test_compressed_psum_shard_map():
     g = jnp.asarray(np.random.default_rng(1).normal(size=64), jnp.float32)
     err = jnp.zeros_like(g)
     from jax.sharding import PartitionSpec as P
-    from repro import jax_compat
-    with mesh, jax_compat.set_mesh(mesh):
-        out, new_err = jax_compat.shard_map(
+    with jax.set_mesh(mesh):
+        out, new_err = jax.shard_map(
             lambda g, e: compressed_psum(g, e, "pod"),
             in_specs=(P(), P()), out_specs=(P(), P()),
             check_vma=False)(g, err)

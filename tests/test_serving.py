@@ -109,7 +109,7 @@ def test_cost_model_decision_and_roundtrip(tmp_path):
                              "host_us_per_query": 1,
                              "device_us_per_wave": {"8": 1}})
     with pytest.raises(ValueError):
-        CostModel(device_us_per_wave={})
+        CostModel(host_us_per_query=1.0, device_us_per_wave={})
 
 
 def test_cost_model_measurement_shape(store, queries):
@@ -127,6 +127,23 @@ def test_cost_model_measurement_shape(store, queries):
     assert model["n_segments"] == len(store.engine.segments)
     cm = CostModel.from_dict(model)
     assert isinstance(cm.prefer_host(1, 8), (bool, np.bool_))
+
+
+def test_no_cost_model_sends_every_wave_to_device():
+    """Without measured costs nothing routes to the scalar host path:
+    every wave, however small, is a device wave."""
+    eng = _GateEngine()
+    eng.gate.set()
+    sched = WaveScheduler([eng], flush_deadline_s=0.001)
+    try:
+        tickets = [sched.submit([i]) for i in range(1, 4)]
+        for t in tickets:
+            t.wait(TIMEOUT)
+    finally:
+        sched.close(timeout=TIMEOUT)
+    st = sched.stats()
+    assert st.host_waves == 0 and st.device_waves >= 1
+    assert all(t.via == "device" for t in tickets)
 
 
 # ------------------------------------------------------------ equivalence
