@@ -277,7 +277,7 @@ class ShardedQueryEngine(QueryEngine):
                               for n in _ROW_FILL})
             row_specs["planes"] = P(self.shard_axes, None, None)
 
-            def body(fps2d, garrs_list):
+            def copr_probe(fps2d, garrs_list):
                 self.compile_count += 1          # runs once per trace
                 q, t = fps2d.shape
                 flat = fps2d.reshape(-1)
@@ -311,7 +311,7 @@ class ShardedQueryEngine(QueryEngine):
                 return acc.reshape(q, t, out_w)
 
             smapped = jax.shard_map(
-                body, mesh=self.mesh,
+                copr_probe, mesh=self.mesh,
                 in_specs=(P(None, None),
                           tuple(row_specs for _ in metas)),
                 out_specs=P(None, None, None), check_vma=False)

@@ -38,6 +38,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
 from .hashing import token_fingerprint
 
 _MIN_Q_BUCKET = 8
@@ -159,7 +160,8 @@ class QueryEngine:
             h = jnp.asarray(host_acc)
             acc = h if acc is None else acc | h
         combined, counts = self._reduce_fn(op)(acc, jnp.asarray(mask))
-        return combined, np.asarray(counts)
+        with tracing.span(tracing.WAVE_SYNC, what="counts"):
+            return combined, np.asarray(counts)
 
     def _device_token_planes(self, fps_dev):
         """(Qb, Tb) device fps -> (Qb, Tb, W) OR-accumulated token planes
@@ -188,7 +190,7 @@ class QueryEngine:
             seg = self.segments[si]
             out_w = self.words
 
-            def body(fps2d, arrs):
+            def copr_probe(fps2d, arrs):
                 self.compile_count += 1          # runs once per trace
                 q, t = fps2d.shape
                 rows = seg.match_bitmap_jnp(fps2d.reshape(-1), arrs)
@@ -198,7 +200,7 @@ class QueryEngine:
                     rows = jnp.pad(rows, ((0, 0), (0, 0), (0, pad)))
                 return rows
 
-            fn = jax.jit(body)
+            fn = jax.jit(copr_probe)
             self._seg_fns[si] = fn
         return fn
 
@@ -207,14 +209,14 @@ class QueryEngine:
         and popcount through the Pallas ``bitset_ops`` kernel."""
         fn = self._reduce_fns.get(op)
         if fn is None:
-            def body(planes, mask):
+            def copr_reduce(planes, mask):
                 from ..kernels.bitset_ops.ops import bitset_reduce_batch
                 self.compile_count += 1
                 neutral = jnp.uint32(0xFFFFFFFF if op == "and" else 0)
                 planes = jnp.where(mask[:, :, None], planes, neutral)
                 return bitset_reduce_batch(planes, op=op)
 
-            fn = jax.jit(body)
+            fn = jax.jit(copr_reduce)
             self._reduce_fns[op] = fn
         return fn
 
@@ -273,7 +275,9 @@ class QueryEngine:
             # slicing to the live rows would save under 2x only on
             # sub-minimum waves while re-tracing per distinct count
             max_hits = _bucket(int(counts.max()), _MIN_HITS_BUCKET)
-            ids = np.asarray(self._extract_fn(max_hits)(bitmaps))
+            ids = self._extract_fn(max_hits)(bitmaps)
+            with tracing.span(tracing.WAVE_SYNC, what="ids"):
+                ids = np.asarray(ids)
             for i in nz:
                 out[int(i)] = ids[int(i), :int(counts[int(i)])] \
                     .astype(np.int64)
@@ -289,12 +293,13 @@ class QueryEngine:
         by the caller so repeated waves reuse the same trace."""
         fn = self._extract_fns.get(max_hits)
         if fn is None:
-            def body(bitmaps):
+            def copr_extract(bitmaps):
                 from ..kernels.bitmap_extract.ops import bitmap_extract
+                self.compile_count += 1
                 ids, _ = bitmap_extract(bitmaps, max_hits=max_hits)
                 return ids
 
-            fn = jax.jit(body)
+            fn = jax.jit(copr_extract)
             self._extract_fns[max_hits] = fn
         return fn
 

@@ -53,6 +53,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from .. import tracing
 from .hashing import token_fingerprint
 from .tokenizer import contains_query_tokens, term_query_tokens
 
@@ -140,24 +141,34 @@ class WaveTicket:
     ``wait()`` blocks for the wave that serves it; ``t_done`` is
     stamped inside the wave (not at ``wait()`` return), so latency
     percentiles measured from tickets are dispatch-accurate.
+    ``query_id`` numbers the scheduler's tickets in submit order;
+    ``wave_id`` is set when a wave worker takes the ticket's wave
+    (``wait_taken()``), and both tag the request's spans.
     """
 
-    __slots__ = ("fps", "op", "t_submit", "t_done", "wave_id", "via",
-                 "_event", "_result", "_error")
+    __slots__ = ("fps", "op", "t_submit", "t_done", "query_id", "wave_id",
+                 "via", "_taken", "_event", "_result", "_error")
 
     def __init__(self, fps: list, op: str):
         self.fps = fps
         self.op = op
         self.t_submit = 0.0
         self.t_done = 0.0
+        self.query_id = -1
         self.wave_id = -1
         self.via = ""            # "host" | "device" once served
+        self._taken = threading.Event()
         self._event = threading.Event()
         self._result = None
         self._error = None
 
     def done(self) -> bool:
         return self._event.is_set()
+
+    def wait_taken(self, timeout: float | None = None) -> None:
+        """Block until a wave worker has taken this ticket's wave."""
+        if not self._taken.wait(timeout):
+            raise TimeoutError(f"query not taken within {timeout}s")
 
     def wait(self, timeout: float | None = None) -> np.ndarray:
         if not self._event.wait(timeout):
@@ -166,16 +177,18 @@ class WaveTicket:
             raise self._error
         return self._result
 
-    def _complete(self, result, wave_id: int, via: str) -> None:
-        self.t_done = time.monotonic()
+    def _take(self, wave_id: int) -> None:
         self.wave_id = wave_id
+        self._taken.set()
+
+    def _complete(self, result, via: str) -> None:
+        self.t_done = time.monotonic()
         self.via = via
         self._result = result
         self._event.set()
 
-    def _fail(self, err: BaseException, wave_id: int) -> None:
+    def _fail(self, err: BaseException) -> None:
         self.t_done = time.monotonic()
-        self.wave_id = wave_id
         self._error = err
         self._event.set()
 
@@ -236,6 +249,7 @@ class WaveScheduler:
         self._inflight = 0          # formed waves not yet completed
         self._ready: deque = deque()  # formed waves awaiting a worker
         self._wave_seq = 0
+        self._query_seq = 0
         self._stop = False
         self._dispatch_done = False
         self._stats = ServeStats()
@@ -313,6 +327,8 @@ class WaveScheduler:
             if self._stop:
                 raise RuntimeError("scheduler is closed")
             ticket.t_submit = time.monotonic()
+            ticket.query_id = self._query_seq
+            self._query_seq += 1
             self._groups.setdefault(key, deque()).append(ticket)
             self._n_pending += 1
             self._stats.submitted += 1
@@ -410,8 +426,11 @@ class WaveScheduler:
                 idx = seq % len(self._engines)
                 engine = self._engines[idx]
                 lock = self._engine_locks[idx]
+                for t in wave[0]:
+                    t._take(seq)
             try:
-                self._run_wave(wave, seq, idx, engine, lock)
+                with tracing.span(tracing.WAVE, wave=seq):
+                    self._run_wave(wave, seq, idx, engine, lock)
             finally:
                 with self._cv:
                     self._inflight -= 1
@@ -439,14 +458,14 @@ class WaveScheduler:
                     results = engine.query_fps_batch(fps_lists, op=op)[:n]
         except BaseException as e:
             for t in tickets:
-                t._fail(e, seq)
+                t._fail(e)
             with self._cv:
                 self._stats.failed += n
                 self._bump_wave_stats(seq, replica, n, q_bucket, use_host)
             return
         via = "host" if use_host else "device"
         for t, r in zip(tickets, results):
-            t._complete(r, seq, via)
+            t._complete(r, via)
         with self._cv:
             self._stats.completed += n
             self._bump_wave_stats(seq, replica, n, q_bucket, use_host)
@@ -542,11 +561,33 @@ class StoreServer:
             out.append(cand[cand < view.n_batches])
         return out
 
+    def _answer(self, view, tokens, term: str, mode: str,
+                timeout: float | None):
+        """One served answer: the ticket's wave, then the exact
+        post-filter.  In a profiler session the client's wait splits
+        into ``copr.serve.queue`` (submit until a worker takes the wave)
+        and ``copr.serve.wave`` (until the candidates are back); off a
+        session it is the one wait."""
+        if not tracing.enabled():
+            ticket = self.scheduler.submit(tokens)
+            cand, = self._served_candidates(view, [ticket], timeout)
+            return view._post_filter(cand, term, mode)
+        deadline = None if timeout is None else time.monotonic() + timeout
+        with tracing.span(tracing.SERVE_QUEUE) as queue_span:
+            ticket = self.scheduler.submit(tokens)
+            ticket.wait_taken(timeout)
+            ids = {"query": ticket.query_id, "wave": ticket.wave_id}
+            queue_span.set_metadata(**ids)
+        with tracing.span(tracing.SERVE_WAVE, **ids):
+            left = (None if deadline is None
+                    else max(deadline - time.monotonic(), 0.0))
+            cand, = self._served_candidates(view, [ticket], left)
+        with tracing.request(**ids):
+            return view._post_filter(cand, term, mode)
+
     def query_term(self, term: str, *, timeout: float | None = None):
-        view = self._view
-        ticket = self.scheduler.submit(term_query_tokens(term))
-        cand, = self._served_candidates(view, [ticket], timeout)
-        return view._post_filter(cand, term, "term")
+        return self._answer(self._view, term_query_tokens(term), term,
+                            "term", timeout)
 
     def query_contains(self, term: str, *, timeout: float | None = None):
         view = self._view
@@ -554,18 +595,22 @@ class StoreServer:
         if not tokens:               # no indexable n-gram: scan the prefix
             cand = np.arange(view.n_batches, dtype=np.int64)
             return view._post_filter(cand, term, "contains")
-        ticket = self.scheduler.submit(tokens)
-        cand, = self._served_candidates(view, [ticket], timeout)
-        return view._post_filter(cand, term, "contains")
+        return self._answer(view, tokens, term, "contains", timeout)
 
     def query_term_batch(self, terms: list[str], *,
                          timeout: float | None = None) -> list:
+        """Every term's ticket submitted at once, so they share waves;
+        the waits are not split into spans (they overlap)."""
         view = self._view
         tickets = [self.scheduler.submit(term_query_tokens(t))
                    for t in terms]
         cands = self._served_candidates(view, tickets, timeout)
-        return [view._post_filter(c, t, "term")
-                for c, t in zip(cands, terms)]
+        out = []
+        for c, t, ticket in zip(cands, terms, tickets):
+            with tracing.request(query=ticket.query_id,
+                                 wave=ticket.wave_id):
+                out.append(view._post_filter(c, t, "term"))
+        return out
 
     @property
     def view(self):

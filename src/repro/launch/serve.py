@@ -6,7 +6,9 @@ a :class:`~repro.core.serving.StoreServer` wave scheduler over a store
 a pool of concurrent clients; prints q/s, p50/p99 latency, and wave
 coalescing stats.  Knobs: ``--clients``, ``--requests`` (per client),
 ``--replicas``, ``--max-live-waves``, ``--flush-deadline-ms``,
-``--cost-model <json>`` (from ``benchmarks/query_throughput.py``).
+``--cost-model <json>`` (from ``benchmarks/query_throughput.py``),
+``--profile-dir DIR`` (a profiler trace of the client phase, with the
+``copr.*`` spans of ``repro.tracing``, for TensorBoard or Perfetto).
 
 LM archs: prefill a batch of prompts, then greedy-decode N tokens with
 the KV cache (the same prefill/decode_step the dry-run lowers at 32k).
@@ -18,6 +20,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from contextlib import nullcontext
 
 import numpy as np
 
@@ -72,13 +75,25 @@ def _serve_dynawarp(args) -> int:
     server.query_term(terms[0], timeout=300)          # warm-up/compile
     threads = [threading.Thread(target=client, args=(ci,), daemon=True)
                for ci in range(args.clients)]
-    t0 = time.perf_counter()
-    for th in threads:
-        th.start()
-    for th in threads:
-        th.join(timeout=600)
-    dt = time.perf_counter() - t0
+    profile = nullcontext()
+    if args.profile_dir:
+        import jax
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0     # the program's spans, not Python's
+        profile = jax.profiler.trace(args.profile_dir,
+                                     create_perfetto_trace=True,
+                                     profiler_options=opts)
+    with profile:
+        t0 = time.perf_counter()
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=600)
+        dt = time.perf_counter() - t0
     server.close()
+    if args.profile_dir:
+        print(f"[serve] profiler trace under {args.profile_dir}",
+              flush=True)
 
     lat_ms = np.asarray([x for per in lat for x in per]) * 1e3
     st = server.scheduler.stats()
@@ -111,6 +126,9 @@ def main(argv=None):
     ap.add_argument("--flush-deadline-ms", type=float, default=2.0)
     ap.add_argument("--cost-model", default=None,
                     help="bench_costmodel.json from query_throughput")
+    ap.add_argument("--profile-dir", default=None,
+                    help="write a profiler trace of the client phase "
+                         "here (dynawarp)")
     args = ap.parse_args(argv)
     from ..compile_cache import enable_compile_cache
     enable_compile_cache()

@@ -25,6 +25,7 @@ import numpy as np
 
 from collections import OrderedDict
 
+from .. import tracing
 from ..baselines.bloom import BloomPerBatch
 from ..baselines.csc import CSCSketch
 from ..baselines.inverted import InvertedIndex
@@ -204,7 +205,83 @@ class IngestStats:
     n_tokens_indexed: int = 0
 
 
-class LogStoreBase:
+class _BatchReader:
+    """Queries over compressed batches, shared by every store and by
+    :class:`StoreSnapshot`: the reader's index names candidate batches
+    (``candidates_term``, ``candidates_contains``,
+    ``candidates_term_batch``), the exact post-filter turns them into
+    lines.  A reader also provides ``blobs``, ``batch_start`` and
+    ``n_batches``, and calls :meth:`_init_batch_cache` with the size of
+    its batch LRU."""
+
+    def _init_batch_cache(self, cap: int) -> None:
+        # LRU of decompressed + lowercased batches; the lock keeps
+        # concurrent serving readers off each other's OrderedDict
+        # mutations (decompression itself runs unlocked)
+        self._batch_cache: OrderedDict[int, tuple] = OrderedDict()
+        self._batch_cache_cap = cap
+        self._batch_cache_lock = threading.Lock()
+
+    def _batch_lower(self, b: int) -> tuple[list[str], list[str]]:
+        """(lines, lowercased lines) of batch ``b`` via a bounded LRU —
+        repeated queries stop re-decompressing + re-lowercasing every
+        candidate batch.  Thread-safe for concurrent serving readers."""
+        with self._batch_cache_lock:
+            hit = self._batch_cache.get(b)
+            if hit is not None:
+                self._batch_cache.move_to_end(b)
+                return hit
+        with tracing.span(tracing.POSTFILTER_DECOMPRESS):
+            lines = decompress_batch(self.blobs[b])
+            entry = (lines, [ln.lower() for ln in lines])
+        with self._batch_cache_lock:
+            self._batch_cache[b] = entry
+            if len(self._batch_cache) > self._batch_cache_cap:
+                self._batch_cache.popitem(last=False)
+        return entry
+
+    def _post_filter(self, candidates: np.ndarray, term: str,
+                     mode: str) -> QueryResult:
+        """The lines of ``candidates`` that hold ``term`` (any case): as
+        a substring in ``contains`` mode, as a rules-1-5 token in
+        ``term`` mode.  Two passes: a substring scan of every candidate
+        batch, then (term mode) one re-tokenize pass over its hits."""
+        term_l = term.lower()
+        with tracing.span(tracing.POSTFILTER):
+            hits = []       # (candidate index, line id, lowered line)
+            for k, b in enumerate(candidates):
+                _, lowered = self._batch_lower(int(b))
+                base = self.batch_start[int(b)]
+                hits.extend((k, base + i, low)
+                            for i, low in enumerate(lowered)
+                            if term_l in low)
+            if mode != "contains":
+                with tracing.span(tracing.POSTFILTER_RETOKENIZE):
+                    hits = [h for h in hits
+                            if self._term_in_line(term_l, h[2])]
+        return QueryResult(matches=[line for _, line, _ in hits],
+                           candidate_batches=np.asarray(candidates),
+                           true_batches=len({k for k, _, _ in hits}),
+                           batches_total=self.n_batches)
+
+    @staticmethod
+    def _term_in_line(term_l: str, line_lower: str) -> bool:
+        """Exact term membership under tokenization rules 1-5."""
+        return term_l.encode() in tokenize_line(line_lower, ngrams=False)
+
+    def query_term(self, term: str) -> QueryResult:
+        return self._post_filter(self.candidates_term(term), term, "term")
+
+    def query_contains(self, term: str) -> QueryResult:
+        return self._post_filter(self.candidates_contains(term), term,
+                                 "contains")
+
+    def query_term_batch(self, terms: list[str]) -> list[QueryResult]:
+        return [self._post_filter(c, t, "term")
+                for c, t in zip(self.candidates_term_batch(terms), terms)]
+
+
+class LogStoreBase(_BatchReader):
     """Batched storage common to all stores."""
     name = "base"
     uses_ngrams = True
@@ -219,12 +296,7 @@ class LogStoreBase:
         self._n_lines = 0
         self.stats = IngestStats()
         self._finished = False
-        # LRU of decompressed + lowercased batches (query post-filter);
-        # the lock keeps concurrent serving readers off each other's
-        # OrderedDict mutations (decompression itself runs unlocked)
-        self._batch_cache: OrderedDict[int, tuple] = OrderedDict()
-        self._batch_cache_cap = batch_cache_size
-        self._batch_cache_lock = threading.Lock()
+        self._init_batch_cache(batch_cache_size)
         # LRU of per-line fingerprints (repeated log lines re-tokenize
         # once; _index_line and the token stats share the same result)
         self._fp_cache: OrderedDict[tuple, np.ndarray] = OrderedDict()
@@ -316,65 +388,10 @@ class LogStoreBase:
             self._fp_cache.popitem(last=False)
         return fps
 
-    def _batch_lower(self, b: int) -> tuple[list[str], list[str]]:
-        """(lines, lowercased lines) of batch ``b`` via a bounded LRU —
-        repeated queries stop re-decompressing + re-lowercasing every
-        candidate batch.  Thread-safe for concurrent serving readers."""
-        with self._batch_cache_lock:
-            hit = self._batch_cache.get(b)
-            if hit is not None:
-                self._batch_cache.move_to_end(b)
-                return hit
-        lines = decompress_batch(self.blobs[b])
-        entry = (lines, [ln.lower() for ln in lines])
-        with self._batch_cache_lock:
-            self._batch_cache[b] = entry
-            if len(self._batch_cache) > self._batch_cache_cap:
-                self._batch_cache.popitem(last=False)
-        return entry
-
-    # ------------------------------------------------------------------ query
-    def _post_filter(self, candidates: np.ndarray, term: str,
-                     mode: str) -> QueryResult:
-        term_l = term.lower()
-        matches: list[int] = []
-        true_batches = 0
-        for b in candidates:
-            _, lowered = self._batch_lower(int(b))
-            base = self.batch_start[int(b)]
-            hit = False
-            for i, low in enumerate(lowered):
-                if term_l not in low:
-                    continue
-                if mode == "contains" or self._term_in_line(term_l, low):
-                    matches.append(base + i)
-                    hit = True
-            true_batches += hit
-        return QueryResult(matches=matches,
-                           candidate_batches=np.asarray(candidates),
-                           true_batches=true_batches,
-                           batches_total=len(self.blobs))
-
-    @staticmethod
-    def _term_in_line(term_l: str, line_lower: str) -> bool:
-        """Exact term membership under tokenization rules 1-5."""
-        return term_l.encode() in tokenize_line(line_lower, ngrams=False)
-
-    def query_term(self, term: str) -> QueryResult:
-        return self._post_filter(self.candidates_term(term), term, "term")
-
-    def query_contains(self, term: str) -> QueryResult:
-        return self._post_filter(self.candidates_contains(term), term,
-                                 "contains")
-
     # batch APIs: stores with a wave-capable index override
     # candidates_term_batch; the default is the sequential host loop.
     def candidates_term_batch(self, terms: list[str]) -> list[np.ndarray]:
         return [self.candidates_term(t) for t in terms]
-
-    def query_term_batch(self, terms: list[str]) -> list[QueryResult]:
-        return [self._post_filter(c, t, "term")
-                for c, t in zip(self.candidates_term_batch(terms), terms)]
 
     @property
     def n_batches(self) -> int:
@@ -1055,7 +1072,7 @@ class DynaWarpStore(LogStoreBase):
             return StoreSnapshot(self)
 
 
-class StoreSnapshot:
+class StoreSnapshot(_BatchReader):
     """Frozen point-in-time reader over a :class:`DynaWarpStore` prefix.
 
     Captured atomically under the store's publish lock (see
@@ -1075,9 +1092,7 @@ class StoreSnapshot:
                                 for x in store.batch_start[:self.n_batches + 1]]
             self.blobs = store.blobs
         self.n_lines = self.batch_start[-1] if self.batch_start else 0
-        self._batch_cache: OrderedDict[int, tuple] = OrderedDict()
-        self._batch_cache_cap = 32
-        self._batch_cache_lock = threading.Lock()
+        self._init_batch_cache(32)
 
     # -------------------------------------------------------- candidates
     def _candidates(self, tokens) -> np.ndarray:
@@ -1102,54 +1117,6 @@ class StoreSnapshot:
             [term_query_tokens(t) for t in terms], op="and")
         return [np.asarray(c, np.int64)[np.asarray(c, np.int64)
                                         < self.n_batches] for c in out]
-
-    # ------------------------------------------------------------ queries
-    def query_term(self, term: str) -> QueryResult:
-        return self._post_filter(self.candidates_term(term), term, "term")
-
-    def query_contains(self, term: str) -> QueryResult:
-        return self._post_filter(self.candidates_contains(term), term,
-                                 "contains")
-
-    def query_term_batch(self, terms: list[str]) -> list[QueryResult]:
-        return [self._post_filter(c, t, "term")
-                for c, t in zip(self.candidates_term_batch(terms), terms)]
-
-    def _batch_lower(self, b: int) -> tuple[list[str], list[str]]:
-        with self._batch_cache_lock:
-            hit = self._batch_cache.get(b)
-            if hit is not None:
-                self._batch_cache.move_to_end(b)
-                return hit
-        lines = decompress_batch(self.blobs[b])
-        entry = (lines, [ln.lower() for ln in lines])
-        with self._batch_cache_lock:
-            self._batch_cache[b] = entry
-            if len(self._batch_cache) > self._batch_cache_cap:
-                self._batch_cache.popitem(last=False)
-        return entry
-
-    def _post_filter(self, candidates: np.ndarray, term: str,
-                     mode: str) -> QueryResult:
-        term_l = term.lower()
-        matches: list[int] = []
-        true_batches = 0
-        for b in candidates:
-            _, lowered = self._batch_lower(int(b))
-            base = self.batch_start[int(b)]
-            hit = False
-            for i, low in enumerate(lowered):
-                if term_l not in low:
-                    continue
-                if mode == "contains" \
-                        or LogStoreBase._term_in_line(term_l, low):
-                    matches.append(base + i)
-                    hit = True
-            true_batches += hit
-        return QueryResult(matches=matches,
-                           candidate_batches=np.asarray(candidates),
-                           true_batches=true_batches,
-                           batches_total=self.n_batches)
 
 
 class CscStore(LogStoreBase):

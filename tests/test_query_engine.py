@@ -7,7 +7,7 @@ import pytest
 from repro.core.batch_builder import build_sealed
 from repro.core.immutable_sketch import ImmutableSketch, build_immutable
 from repro.core.query import query_and, query_or
-from repro.core.query_engine import QueryEngine
+from repro.core.query_engine import _MIN_HITS_BUCKET, QueryEngine, _bucket
 from repro.core.segment import SegmentWriter
 
 
@@ -114,25 +114,62 @@ def test_one_device_upload_per_segment(monkeypatch):
 
 
 # ---------------------------------------------------------------- jit cache
+def _hits_bucket(results) -> int | None:
+    """The extract program's ``max_hits`` bucket of a wave's answers
+    (None: no answer has a hit, so the wave runs no extract)."""
+    most = max(len(r) for r in results)
+    return _bucket(most, _MIN_HITS_BUCKET) if most else None
+
+
 def test_one_compile_per_bucket_shape():
+    """The probe and the reduce trace once per (Q, T) bucket, the extract
+    once per hit-count bucket; a repeated shape traces nothing."""
     _, fps, posts = _corpus(4)
     sk = build_immutable(build_sealed(fps, posts))
     eng = QueryEngine([sk])
     uniq = [int(x) for x in np.unique(fps)[:40]]
+    seen: set[int] = set()
 
-    eng.query_fps_batch([uniq[:1]])          # bucket (8, 1)
-    base = eng.compile_count
-    assert base > 0
+    def wave(queries) -> int:
+        """Traces of one wave, less one for an extract bucket not seen."""
+        before = eng.compile_count
+        hits = _hits_bucket(eng.query_fps_batch(queries))
+        fresh = hits is not None and hits not in seen
+        seen.add(hits)
+        return eng.compile_count - before - fresh
+
+    assert wave([uniq[:1]]) == 2             # bucket (8, 1): probe, reduce
     for _ in range(4):                       # same bucket -> no retrace
-        eng.query_fps_batch([uniq[1:2], uniq[2:3]])
-    assert eng.compile_count == base
-
-    eng.query_fps_batch([uniq[:3]])          # bucket (8, 4): +probe +reduce
-    grown = eng.compile_count
-    assert grown == base + 2
+        assert wave([uniq[1:2], uniq[2:3]]) == 0
+    assert wave([uniq[:3]]) == 2             # bucket (8, 4): +probe +reduce
     for _ in range(3):
-        eng.query_fps_batch([uniq[3:6], uniq[6:9]])
-    assert eng.compile_count == grown
+        assert wave([uniq[3:6], uniq[6:9]]) == 0
+
+
+def test_extract_traces_are_counted():
+    """A wave whose answers reach a new hit-count bucket traces one more
+    program, the extract, and counts it; the same bucket again does not."""
+    _, fps, posts = _corpus(4)
+    sk = build_immutable(build_sealed(fps, posts))
+    eng = QueryEngine([sk])
+    uniq = [int(x) for x in np.unique(fps)]
+    by_bucket: dict[int, int] = {}
+    for fp in uniq:
+        hits = _hits_bucket(eng.query_fps_batch([[fp]]))
+        if hits is not None:
+            by_bucket.setdefault(hits, fp)
+        if len(by_bucket) == 2:
+            break
+    assert len(by_bucket) == 2, "the corpus needs two hit-count buckets"
+    small, large = sorted(by_bucket.items())
+    eng = QueryEngine([sk])
+    eng.query_fps_batch([[small[1]]])        # probe, reduce, extract
+    assert eng.compile_count == 3
+    eng.query_fps_batch([[large[1]]])        # the same (Q, T): extract only
+    assert eng.compile_count == 4
+    eng.query_fps_batch([[small[1]]])
+    eng.query_fps_batch([[large[1]]])
+    assert eng.compile_count == 4
 
 
 # ------------------------------------------------------------- store level
