@@ -44,14 +44,18 @@ def compress_batch(lines: list[str]) -> bytes:
     return _TAG_ZLIB + zlib.compress(raw, 6)
 
 
-def decompress_batch(blob: bytes) -> list[str]:
+def decompress_raw(blob: bytes) -> bytes:
+    """The UTF-8 bytes of a blob's batch: its lines joined by ``b"\\n"``."""
     tag, payload = blob[:1], blob[1:]
     if tag == _TAG_ZLIB:
-        raw = zlib.decompress(payload)
-    else:  # zstd-tagged, or legacy untagged zstd blob
-        if not HAVE_ZSTD:
-            raise RuntimeError(
-                "this store was written with zstandard; install it to read")
-        dctx = _ctx("dctx", zstd.ZstdDecompressor)
-        raw = dctx.decompress(payload if tag == _TAG_ZSTD else blob)
-    return raw.decode("utf-8").split("\n")
+        return zlib.decompress(payload)
+    # zstd-tagged, or legacy untagged zstd blob
+    if not HAVE_ZSTD:
+        raise RuntimeError(
+            "this store was written with zstandard; install it to read")
+    dctx = _ctx("dctx", zstd.ZstdDecompressor)
+    return dctx.decompress(payload if tag == _TAG_ZSTD else blob)
+
+
+def decompress_batch(blob: bytes) -> list[str]:
+    return decompress_raw(blob).decode("utf-8").split("\n")

@@ -41,7 +41,7 @@ from ..core.segment import (SegmentWriter, merge_sealed, sealed_postings,
 from ..core.tokenizer import (contains_query_tokens, term_query_tokens,
                               tokenize_line)
 from .blobfile import BlobFile
-from .compress import compress_batch, decompress_batch
+from .compress import compress_batch, decompress_raw
 
 MANIFEST_NAME = "MANIFEST.json"
 # format 2: adds ``finished`` (live-ingest manifests published at every
@@ -205,6 +205,28 @@ class IngestStats:
     n_tokens_indexed: int = 0
 
 
+def _lines_holding(low: bytes, needle: bytes,
+                   i: int) -> list[tuple[int, bytes]]:
+    """``(line index, line)`` of each line of ``low`` (lines joined by
+    ``b"\\n"``) that holds ``needle``, whose first occurrence is at
+    ``i``.  A walk from hit to hit that counts newlines as it goes, so
+    each byte is scanned a bounded number of times however dense the
+    hits.  An occurrence across a line break is in no line."""
+    out = []
+    line = pos = 0
+    while i >= 0:
+        start = low.rfind(b"\n", 0, i) + 1
+        line += low.count(b"\n", pos, start)
+        end = low.find(b"\n", i)
+        if end < 0:
+            end = len(low)
+        if i + len(needle) <= end:
+            out.append((line, low[start:end]))
+        pos = start
+        i = low.find(needle, end + 1)
+    return out
+
+
 class _BatchReader:
     """Queries over compressed batches, shared by every store and by
     :class:`StoreSnapshot`: the reader's index names candidate batches
@@ -218,47 +240,59 @@ class _BatchReader:
         # LRU of decompressed + lowercased batches; the lock keeps
         # concurrent serving readers off each other's OrderedDict
         # mutations (decompression itself runs unlocked)
-        self._batch_cache: OrderedDict[int, tuple] = OrderedDict()
+        self._batch_cache: OrderedDict[int, bytes] = OrderedDict()
         self._batch_cache_cap = cap
         self._batch_cache_lock = threading.Lock()
 
-    def _batch_lower(self, b: int) -> tuple[list[str], list[str]]:
-        """(lines, lowercased lines) of batch ``b`` via a bounded LRU —
-        repeated queries stop re-decompressing + re-lowercasing every
-        candidate batch.  Thread-safe for concurrent serving readers."""
+    def _batch_lower(self, b: int) -> bytes:
+        """The lowercased UTF-8 bytes of batch ``b``, lines joined by
+        ``b"\\n"``, via a bounded LRU: repeated queries stop
+        re-decompressing + re-lowercasing every candidate batch.
+        Thread-safe for concurrent serving readers."""
         with self._batch_cache_lock:
             hit = self._batch_cache.get(b)
             if hit is not None:
                 self._batch_cache.move_to_end(b)
                 return hit
-        with tracing.span(tracing.POSTFILTER_DECOMPRESS):
-            lines = decompress_batch(self.blobs[b])
-            entry = (lines, [ln.lower() for ln in lines])
+        with tracing.span(tracing.POSTFILTER_DECOMPRESS) as span:
+            raw = decompress_raw(self.blobs[b])
+            is_ascii = raw.isascii()
+            if is_ascii:    # bytes.lower() is str.lower() on ASCII
+                low = raw.lower()
+            else:           # lowering never makes or removes a newline
+                low = "\n".join(ln.lower() for ln in
+                                raw.decode("utf-8").split("\n")).encode()
+            if tracing.enabled():
+                span.set_metadata(ascii=int(is_ascii))
         with self._batch_cache_lock:
-            self._batch_cache[b] = entry
+            self._batch_cache[b] = low
             if len(self._batch_cache) > self._batch_cache_cap:
                 self._batch_cache.popitem(last=False)
-        return entry
+        return low
 
     def _post_filter(self, candidates: np.ndarray, term: str,
                      mode: str) -> QueryResult:
         """The lines of ``candidates`` that hold ``term`` (any case): as
         a substring in ``contains`` mode, as a rules-1-5 token in
-        ``term`` mode.  Two passes: a substring scan of every candidate
-        batch, then (term mode) one re-tokenize pass over its hits."""
+        ``term`` mode.  Two passes: a substring search of every candidate
+        batch's lowercased bytes, then (term mode) one re-tokenize pass
+        over its hit lines."""
         term_l = term.lower()
+        needle = term_l.encode("utf-8")
         with tracing.span(tracing.POSTFILTER):
             hits = []       # (candidate index, line id, lowered line)
             for k, b in enumerate(candidates):
-                _, lowered = self._batch_lower(int(b))
-                base = self.batch_start[int(b)]
-                hits.extend((k, base + i, low)
-                            for i, low in enumerate(lowered)
-                            if term_l in low)
+                b = int(b)
+                low = self._batch_lower(b)
+                i = low.find(needle)
+                if i >= 0:
+                    base = self.batch_start[b]
+                    hits.extend((k, base + j, ln)
+                                for j, ln in _lines_holding(low, needle, i))
             if mode != "contains":
                 with tracing.span(tracing.POSTFILTER_RETOKENIZE):
                     hits = [h for h in hits
-                            if self._term_in_line(term_l, h[2])]
+                            if self._term_in_line(term_l, h[2].decode())]
         return QueryResult(matches=[line for _, line, _ in hits],
                            candidate_batches=np.asarray(candidates),
                            true_batches=len({k for k, _, _ in hits}),

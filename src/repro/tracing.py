@@ -16,7 +16,9 @@ Spans and the thread that opens them:
   ``copr.postfilter``   client: the whole exact post-filter of one
                         answer
   ``copr.postfilter.decompress``  client: one batch-LRU miss, zstd
-                        decompress and lower-case of one batch
+                        decompress and lower-case of one batch into
+                        one byte string (``ascii``: 1 where the batch
+                        is ASCII and lowered as bytes, else 0)
   ``copr.postfilter.retokenize``  client: term mode, re-tokenizing one
                         answer's substring hits in one pass
   ``copr.wave``         wave worker: pick-up until every ticket of the

@@ -1,12 +1,17 @@
 """End-to-end log-store behaviour: the five §5 implementations against
 brute-force ground truth, plus the paper's qualitative claims at test
 scale (sizes, error rates, speedups)."""
+import zlib
+
 import numpy as np
 import pytest
 
+from repro.core.tokenizer import tokenize_line
+from repro.logstore import compress
 from repro.logstore.datasets import (extracted_term_queries, id_queries,
                                      ip_queries, present_id_queries)
-from repro.logstore.store import ALL_STORES, DynaWarpStore, ScanStore
+from repro.logstore.store import (ALL_STORES, DynaWarpStore, ScanStore,
+                                  _BatchReader)
 
 
 @pytest.fixture(scope="module")
@@ -125,3 +130,83 @@ def test_serialization_roundtrip(small_dataset, tmp_path):
         from repro.core.tokenizer import term_query_tokens
         b = query_and(loaded, term_query_tokens(t))
         np.testing.assert_array_equal(np.sort(a), np.sort(b))
+
+
+#: hand-built batches: mixed case, an empty line, hits at the first and
+#: last byte of a line and of a batch, a batch of hits only, non-ASCII
+#: lines (Kelvin sign, dotted capital I, a final Greek sigma at a line's
+#: end), and a batch dense with hit lines
+POSTFILTER_BATCHES = [
+    ["Alpha Beta", "GAMMA delta", "", "x alphA"],
+    ["err 1", "ERR 2", "xerr"],
+    ["Temp 5\u212a ok", "\u0130stanbul caf\u00e9", "\u039f\u0394\u039f\u03a3",
+     "\u03a3 plain err"],
+    ["zeta one", "two zeta"],
+    [f"Dense {j}" if j % 3 else f"sparse {j}" for j in range(60)],
+]
+POSTFILTER_TERMS = {
+    "mixed_case": "ALPHA",
+    "first_and_last_byte": "zeta",
+    "every_line_hits": "err",
+    "kelvin_sign": "5K",
+    "dotted_capital_i": "\u0130stanbul",
+    "ascii_i_in_dotted_i": "i",
+    "utf8_needle": "CAF\u00c9",
+    "final_sigma_at_line_end": "\u03b4\u03bf\u03c2",
+    "non_final_sigma": "\u03b4\u03bf\u03c3",
+    "empty": "",
+    "newline": "\n",
+    "spans_line_break": "beta\ngamma",
+    "spans_batch_break": "x alpha\nerr",
+    "absent": "omega",
+    "dense_hits": "DENSE",
+}
+
+
+class _HandBatches(_BatchReader):
+    """A reader over ``POSTFILTER_BATCHES`` blobs of one codec."""
+
+    def __init__(self, codec: str):
+        if codec == "zstd":
+            zstd = pytest.importorskip("zstandard")
+            pack = lambda raw: (compress._TAG_ZSTD
+                                + zstd.ZstdCompressor().compress(raw))
+        else:
+            pack = lambda raw: compress._TAG_ZLIB + zlib.compress(raw)
+        self.blobs = [pack("\n".join(b).encode()) for b in POSTFILTER_BATCHES]
+        self.batch_start = list(np.cumsum(
+            [0] + [len(b) for b in POSTFILTER_BATCHES]).tolist())
+        self.n_batches = len(POSTFILTER_BATCHES)
+        self._init_batch_cache(len(POSTFILTER_BATCHES))
+
+
+def _scan_lines(reader, term: str, mode: str) -> list[int]:
+    """The plain per-line scan the post-filter must equal."""
+    term_l, out, line_id = term.lower(), [], 0
+    for blob in reader.blobs:
+        for line in compress.decompress_batch(blob):
+            low = line.lower()
+            if term_l in low and (mode == "contains" or term_l.encode()
+                                  in tokenize_line(low, ngrams=False)):
+                out.append(line_id)
+            line_id += 1
+    return out
+
+
+@pytest.mark.parametrize("codec", ["zstd", "zlib"])
+@pytest.mark.parametrize("mode", ["contains", "term"])
+@pytest.mark.parametrize("case", sorted(POSTFILTER_TERMS))
+def test_post_filter_equals_a_per_line_scan(case, mode, codec):
+    reader = _HandBatches(codec)
+    term = POSTFILTER_TERMS[case]
+    want = _scan_lines(reader, term, mode)
+    cands = np.arange(reader.n_batches)
+    for _ in range(2):      # every batch an LRU miss, then every one a hit
+        got = reader._post_filter(cands, term, mode)
+        assert got.matches == want, (case, mode, got.matches)
+        assert got.true_batches == len(
+            {np.searchsorted(reader.batch_start, i, "right") for i in want})
+    if case in ("newline", "spans_line_break", "spans_batch_break"):
+        assert want == []
+    if case == "every_line_hits" and mode == "contains":
+        assert set(range(4, 7)) <= set(want)

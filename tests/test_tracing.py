@@ -5,7 +5,8 @@ threads, once inside a profiler session and once outside.  The trace
 must hold every span of ``repro.tracing``, each child inside its parent
 on the parent's thread, request ids shared between the client's spans
 and the wave worker's, the named device programs and no ``body``; the
-answers must be the same with the profiler on and off.
+answers must be the same with the profiler on and off.  A store's batch
+LRU opens one decompress span per miss and none per hit.
 """
 import glob
 import os
@@ -13,6 +14,7 @@ import threading
 import warnings
 
 import jax
+import numpy as np
 import pytest
 
 from repro import tracing
@@ -90,7 +92,6 @@ def traced(store, requests, tmp_path_factory):
     """(answers, host lines) of one served round inside a profiler
     session; each host line is (thread name, [(name, start, end,
     stats)])."""
-    from jax.profiler import ProfileData
     _serve(store, requests[:2])         # compile outside the session
     log_dir = str(tmp_path_factory.mktemp("trace"))
     opts = jax.profiler.ProfileOptions()
@@ -100,6 +101,12 @@ def traced(store, requests, tmp_path_factory):
         answers = _serve(store, requests)
     finally:
         jax.profiler.stop_trace()
+    return answers, _host_lines(log_dir)
+
+
+def _host_lines(log_dir: str) -> list:
+    """The host lines of the one trace under ``log_dir``."""
+    from jax.profiler import ProfileData
     path, = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
                       recursive=True)
     lines = []
@@ -112,7 +119,7 @@ def traced(store, requests, tmp_path_factory):
                 lines.append((ln.name, [
                     (e.name, e.start_ns, e.start_ns + e.duration_ns,
                      dict(e.stats)) for e in ln.events]))
-    return answers, lines
+    return lines
 
 
 def _spans(lines, name):
@@ -126,6 +133,30 @@ def test_every_span_appears(traced):
         assert _spans(lines, name), name
     whats = {ev[3].get("what") for _, ev in _spans(lines, tracing.WAVE_SYNC)}
     assert whats == {"counts", "ids"}
+    assert all(ev[3]["ascii"] in (0, 1)
+               for _, ev in _spans(lines, tracing.POSTFILTER_DECOMPRESS))
+
+
+def test_decompress_span_once_per_batch_cache_miss(tmp_path):
+    """One ``copr.postfilter.decompress`` span per LRU miss and none on
+    a hit, each with its batch's ``ascii`` stat."""
+    from repro.logstore.store import ScanStore
+    s = ScanStore(batch_lines=2, batch_cache_size=8)
+    s.ingest(["INFO a", "WARN b", "caf\u00e9 c", "INFO d", "WARN e"])
+    s.finish()
+    cands = np.arange(s.n_batches)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        first = s.query_contains("info").matches    # every batch misses
+        second = s._post_filter(cands, "info", "contains").matches  # hits
+    finally:
+        jax.profiler.stop_trace()
+    assert first == second == [0, 3]
+    spans = [ev for _, ev in _spans(_host_lines(str(tmp_path)),
+                                    tracing.POSTFILTER_DECOMPRESS)]
+    assert sorted(ev[3]["ascii"] for ev in spans) == [0, 1, 1]
 
 
 def test_each_child_lies_within_its_parent_on_its_thread(traced):
