@@ -57,9 +57,11 @@ _HEX = np.frombuffer(b"0123456789abcdef", np.uint8)
 
 @dataclass
 class Corpus:
+    """What a generator gives: every generator under ``bench/corpora/``
+    returns one.  Scenarios read ``lines`` and ``sources``."""
     lines: list[str]
     sources: np.ndarray        # (N,) int32 source of each line
-    templates: np.ndarray      # (N,) int32 template of each line
+    templates: np.ndarray | None = None    # (N,) int32 template of each line
 
     @property
     def n_lines(self) -> int:

@@ -2,12 +2,29 @@
 
 Everything that belongs to one configuration, traffic mix or per-layer
 metric lives in a file of its own, found by the name in
-``BENCHMARK.json``:
+``BENCHMARK.json`` (or in the config or traffic file that names it), so
+a new deployment arrives as new files and edits none:
 
-  * ``bench/configs/<config>.json``: the deployment (corpus, store and
-    serving settings, the guarantee it gives);
+  * ``bench/configs/<config>.json``: the deployment.  ``corpus``: the
+    generator's settings, and optionally ``generator``, which names
+    ``bench/corpora/<generator>.py`` (without it, ``bench/corpus.py``);
+    ``store``: ``DynaWarpStore`` settings of the store as built;
+    optionally ``open``: keyword arguments of ``DynaWarpStore.open``
+    (lists become tuples, e.g. ``"shard_axes": ["data"]``);
+    ``serving``: ``store.serving`` settings; ``guarantee``: what every
+    answer is held to;
+  * ``bench/corpora/<generator>.py``: ``generate(**corpus settings) ->
+    bench.corpus.Corpus`` (``lines``, one string per line, and
+    ``sources``, the source of each line, are what scenarios read),
+    the same lines for the same settings;
   * ``bench/traffic/<traffic>.json``: the load (loop kind, rate or
-    clients, the query pool by scenario);
+    clients, the query pool by scenario, and optionally ``pool_seed``,
+    which draws one pool for every ``--seed``);
+  * ``bench/queries/<scenario>.py``: a scenario ``bench/scenarios.py``
+    does not define: ``OP`` and ``draw(rng, corpus, n, traffic) ->
+    list[str]``, and for an op the harness does not know, ``tokens(text)``,
+    ``send(server, text, timeout)`` and ``answer(lines, texts) ->
+    {text: ids}`` (``bench/ops.py``);
   * ``bench/metrics/<metric>.py`` (or ``<part before the first dot>.py``):
     ``read(run) -> float | None`` for one per-layer metric.
 
@@ -21,7 +38,6 @@ window with the plain reference.
 """
 from __future__ import annotations
 
-import importlib.util
 import json
 import os
 import sys
@@ -31,7 +47,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from . import arrivals, corpus as corpus_mod, loops, measure, reference
+from . import arrivals, byname, corpus as corpus_mod, loops, measure, ops
 from . import scenarios, store_cache
 
 BENCH_DIR = store_cache.BENCH_DIR
@@ -129,10 +145,18 @@ class Run:
         return [r for r in self.records if r.done and not r.error]
 
 
-def _tokens(op: str, text: str) -> list:
-    from repro.core.tokenizer import contains_query_tokens, term_query_tokens
-    return term_query_tokens(text) if op == "term" \
-        else contains_query_tokens(text)
+def make_corpus(cfg: dict) -> corpus_mod.Corpus:
+    """The configuration's corpus, from its generator."""
+    params = dict(cfg["corpus"])
+    name = params.pop("generator", None)
+    gen = byname.load("corpora", name) if name else corpus_mod
+    return gen.generate(**params)
+
+
+def open_settings(cfg: dict) -> dict:
+    """Keyword arguments of ``DynaWarpStore.open``, lists as tuples."""
+    return {k: tuple(v) if isinstance(v, list) else v
+            for k, v in cfg.get("open", {}).items()}
 
 
 def _pow2(n: int, lo: int) -> int:
@@ -140,13 +164,13 @@ def _pow2(n: int, lo: int) -> int:
 
 
 def q_buckets(config: dict, traffic: dict) -> list[int]:
-    """The Q buckets the cell's waves can take: all of them under an open
-    loop; under a closed loop of ``c`` clients, those up to the first
-    that holds ``c`` queries."""
+    """The Q buckets the cell's waves can take.  No more queries are in
+    flight than the open loop's ``workers`` or the closed loop's
+    ``clients``, so a wave holds at most that many: the buckets up to the
+    first that holds them."""
     buckets = sorted(config["serving"]["bucket_sizes"])
-    if traffic["loop"] == "open":
-        return buckets
-    cap = next((b for b in buckets if b >= traffic["clients"]), buckets[-1])
+    most = traffic["workers" if traffic["loop"] == "open" else "clients"]
+    cap = next((b for b in buckets if b >= most), buckets[-1])
     return [b for b in buckets if b <= cap]
 
 
@@ -176,30 +200,29 @@ def warm(server, token_lists: list, buckets: list[int]) -> None:
                     eng.query_batch([token_lists[i]] * qb)
 
 
-def _serve_once(server, queries: list) -> None:
+def _serve_once(send, queries: list) -> None:
     """One request of each scenario through the server's own threads."""
     seen = set()
     for scenario, op, text in queries:
         if scenario not in seen:
             seen.add(scenario)
-            fn = server.query_term if op == "term" else server.query_contains
-            fn(text, timeout=loops.LATE_S)
+            send(op, text)
 
 
-def _answers(config_name: str, cfg: dict, traffic_name: str, seed: int,
-             queries: list, lines) -> dict:
-    """Reference answers for every pool query, from the cache or a scan."""
+def _answers(config_name: str, cfg: dict, traffic_name: str,
+             traffic: dict, seed: int, queries: list, lines,
+             op_table: dict) -> dict:
+    """Reference answers for every pool query, from the cache or a scan
+    (``ops.answers``)."""
     want = {(op, text) for _, op, text in queries}
-    have = store_cache.load_answers(config_name, cfg, traffic_name, seed)
+    files = scenarios.module_files(traffic)
+    have = store_cache.load_answers(config_name, cfg, traffic_name, seed,
+                                    files)
     if want <= set(have):
         return have
-    terms = [t for op, t in want if op == "term"]
-    needles = [t for op, t in want if op == "contains"]
-    term_ans, contains_ans = reference.answer(lines(), terms, needles)
-    answers = {("term", t): term_ans[t.lower()] for t in terms}
-    answers.update({("contains", t): contains_ans[t.lower()]
-                    for t in needles})
-    store_cache.save_answers(config_name, cfg, traffic_name, seed, answers)
+    answers = ops.answers(op_table, lines(), want)
+    store_cache.save_answers(config_name, cfg, traffic_name, seed, files,
+                             answers)
     return answers
 
 
@@ -237,13 +260,8 @@ def end_to_end(name: str, run: Run, extra: dict) -> float:
 
 def load_reader(name: str):
     for stem in (name, name.split(".")[0]):
-        path = os.path.join(BENCH_DIR, "metrics", f"{stem}.py")
-        if os.path.exists(path):
-            spec = importlib.util.spec_from_file_location(
-                f"bench_metric_{stem.replace('.', '_')}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+        if os.path.exists(byname.path("metrics", stem)):
+            return byname.load("metrics", stem).read
     raise SystemExit(f"bench: no reader bench/metrics/{name}.py")
 
 
@@ -272,25 +290,44 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
     from repro.logstore.store import DynaWarpStore
     enable_compile_cache()
     clock = CompileClock()
+    last = time.monotonic()
+    parts = {"start": last - t_proc}      # set-up's parts, for the log
 
-    corpus = corpus_mod.generate(**cfg["corpus"])
+    def part(name: str) -> None:
+        nonlocal last
+        now = time.monotonic()
+        parts[name], last = now - last, now
+
+    corpus = make_corpus(cfg)
+    part("corpus")
     path = store_cache.store_path(config_name, cfg, lambda: corpus.lines)
     raw_bytes = corpus.raw_bytes()
     stored = store_cache.stored_bytes(path)
-    queries = scenarios.pool(traffic, corpus, seed)
-    token_lists = [_tokens(op, text) for _, op, text in queries]
-    store = DynaWarpStore.open(path)
+    part("store")
+    queries, op_table = scenarios.pool(traffic, corpus, seed)
+    token_lists = [op_table[op].tokens(text) for _, op, text in queries]
+    part("pool")
+    store = DynaWarpStore.open(path, **open_settings(cfg))
     if fault is not None:
         fault(store)
     n_batches = store.n_batches
     server = store.serving(**cfg["serving"])
+    part("open")
+
+    def send(op, text):
+        return op_table[op].send(server, text, loops.LATE_S)
     try:
         warm(server, token_lists, q_buckets(cfg, traffic))
-        _serve_once(server, queries)
+        part("warm")
+        _serve_once(send, queries)
         stats0 = server.scheduler.stats()
+        part("serve_once")
         records, t0, t_end, marks, tr = _drive(
-            server, queries, traffic, seed, seconds, trace)
+            server, send, queries, traffic, seed, seconds, trace)
         setup_s = t0 - t_proc
+        log("set-up " + ", ".join(f"{k} {v:.2f} s"
+                                  for k, v in parts.items())
+            + f", window start {t0 - t_proc - sum(parts.values()):.2f} s")
     finally:
         server.close()
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
@@ -305,8 +342,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
         f"({clock.hits} cache hits, {clock.misses} misses)")
 
     t_ref = time.monotonic()
-    answers = _answers(config_name, cfg, traffic_name, seed, queries,
-                       lambda: corpus.lines)
+    answers = _answers(config_name, cfg, traffic_name, traffic, seed,
+                       queries, lambda: corpus.lines, op_table)
     checks = check(records, queries, answers)
     log(f"reference and check {time.monotonic() - t_ref:.1f} s")
 
@@ -348,10 +385,10 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
     return out
 
 
-def _drive(server, queries, traffic, seed, seconds, trace):
-    """The measured window: load in a thread of its own, the scheduler's
-    counters read at the window's close, the trace (if any) stopped
-    there."""
+def _drive(server, send, queries, traffic, seed, seconds, trace):
+    """The measured window: load in a thread of its own, each request
+    through ``send(op, text)``, the scheduler's counters read at the
+    window's close, the trace (if any) stopped there."""
     from contextlib import nullcontext
     from . import trace as trace_mod
     span = None
@@ -365,11 +402,12 @@ def _drive(server, queries, traffic, seed, seconds, trace):
     if traffic["loop"] == "open":
         due, order = arrivals.open_schedule(
             len(queries), traffic["rate_qps"], seconds, seed)
+        records = loops.open_records(due, order, t0)
 
         def load():
             box["records"] = loops.open_loop(
-                server, queries, due, order, t0,
-                workers=traffic["workers"], span=span)
+                send, queries, records, workers=traffic["workers"],
+                span=span)
     else:
         seqs = arrivals.closed_sequences(
             [q[0] for q in queries], traffic["clients"],
@@ -377,7 +415,7 @@ def _drive(server, queries, traffic, seed, seconds, trace):
 
         def load():
             box["records"] = loops.closed_loop(
-                server, queries, seqs, t0, seconds, span=span)
+                send, queries, seqs, t0, seconds, span=span)
 
     def guarded():
         try:

@@ -1,18 +1,19 @@
 """Load generators over a ``StoreServer``: one open loop, one closed loop.
 
-Both send each query through ``StoreServer.query_term`` or
-``query_contains``, so a request's time covers its probe wave and the
-exact post-filter that turns candidates into lines.  Each request leaves
+Both send each query through ``send(op, text)``, which the harness binds
+to the op's request on the ``StoreServer`` (``bench/ops.py``), so a
+request's time covers its probe wave and the exact post-filter that
+turns candidates into lines.  Each request leaves
 a :class:`Record`; the window's metrics and the check of its answers are
 made from the records alone.
 """
 from __future__ import annotations
 
+import queue
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
 from contextlib import nullcontext
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 import numpy as np
 
@@ -22,8 +23,11 @@ from .trace import QUERY_SPAN
 LATE_S = 60.0
 
 
-@dataclass
+@dataclass(slots=True)
 class Record:
+    """One request.  Slotted and without a lock of its own: an open loop
+    makes one per arrival, and a garbage collection that the load
+    generator's own objects set off would pause the server too."""
     query: int                  # index into the pool
     due: float                  # monotonic time the request was due
     start: float = 0.0          # when a worker sent it
@@ -32,53 +36,59 @@ class Record:
     candidates: int = 0
     true_batches: int = 0
     error: str = ""
-    _event: threading.Event = field(default_factory=threading.Event,
-                                    repr=False)
 
 
-def _serve(server, query: tuple, rec: Record, span) -> None:
+def _serve(send, query: tuple, rec: Record, span) -> None:
     _, op, text = query
     rec.start = time.monotonic()
     try:
         with span(QUERY_SPAN):
-            fn = server.query_term if op == "term" else server.query_contains
-            res = fn(text, timeout=LATE_S)
+            res = send(op, text)
         rec.matches = np.asarray(res.matches, np.int64)
         rec.candidates = len(res.candidate_batches)
         rec.true_batches = int(res.true_batches)
         rec.done = time.monotonic()
     except Exception as e:          # a failed query is a record, not a crash
         rec.error = f"{type(e).__name__}: {e}"
-    finally:
-        rec._event.set()
 
 
-def open_loop(server, queries, due_s, order, t0: float, *, workers: int,
+def open_records(due_s, order, t0: float) -> list[Record]:
+    """One record per arrival: query ``order[i]``, due at
+    ``t0 + due_s[i]``.  Made before the window opens."""
+    return [Record(int(q), t0 + float(d)) for d, q in zip(due_s, order)]
+
+
+def open_loop(send, queries, records, *, workers: int,
               span=None) -> list[Record]:
-    """Send query ``order[i]`` at ``t0 + due_s[i]`` whatever the server
-    does, each on a worker of its own; returns once every answer is in
-    or ``LATE_S`` past the last arrival."""
+    """Send each record's query when it is due, whatever the server
+    does, through a pool of ``workers`` client threads started first;
+    returns once every answer is in or ``LATE_S`` past the last
+    arrival."""
     span = span or (lambda name: nullcontext())
-    records = [Record(int(q), t0 + float(d)) for d, q in zip(due_s, order)]
-    with ThreadPoolExecutor(max_workers=workers,
-                            thread_name_prefix="bench-client") as pool:
-        futures = []
-        for rec in records:
-            wait = rec.due - time.monotonic()
-            if wait > 0:
-                time.sleep(wait)
-            futures.append(pool.submit(_serve, server, queries[rec.query],
-                                       rec, span))
-        deadline = time.monotonic() + LATE_S
-        for rec in records:
-            rec._event.wait(max(deadline - time.monotonic(), 0))
-        for f in futures:
-            if f.done():
-                f.result()
+    todo: queue.SimpleQueue = queue.SimpleQueue()
+
+    def client() -> None:
+        while (rec := todo.get()) is not None:
+            _serve(send, queries[rec.query], rec, span)
+
+    threads = [threading.Thread(target=client, name=f"bench-client-{c}",
+                                daemon=True) for c in range(workers)]
+    for th in threads:
+        th.start()
+    for rec in records:
+        wait = rec.due - time.monotonic()
+        if wait > 0:
+            time.sleep(wait)
+        todo.put(rec)
+    for _ in threads:
+        todo.put(None)
+    deadline = time.monotonic() + LATE_S
+    for th in threads:
+        th.join(max(deadline - time.monotonic(), 0))
     return records
 
 
-def closed_loop(server, queries, sequences, t0: float, seconds: float, *,
+def closed_loop(send, queries, sequences, t0: float, seconds: float, *,
                 span=None) -> list[Record]:
     """Each client sends its sequence back to back from ``t0`` until the
     window closes; a query sent inside the window is waited for."""
@@ -95,7 +105,7 @@ def closed_loop(server, queries, sequences, t0: float, seconds: float, *,
         while time.monotonic() < t_end:
             rec = Record(seq[k % len(seq)], time.monotonic())
             per_client[c].append(rec)
-            _serve(server, queries[rec.query], rec, span)
+            _serve(send, queries[rec.query], rec, span)
             k += 1
 
     threads = [threading.Thread(target=client, args=(c,),

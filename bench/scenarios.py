@@ -18,8 +18,12 @@ the yardstick cannot move with the program:
     the traffic's ``extracted_lines``, so that every seed's pool costs
     about the same.
 
-A query is ``(scenario, op, text)`` with ``op`` ``"term"`` or
-``"contains"``.
+A query is ``(scenario, op, text)``.  The seven scenarios above use the
+built-in ops ``"term"`` and ``"contains"`` (``bench/ops.py``).  A pool
+entry of any other name is the module ``bench/queries/<name>.py``, which
+gives ``OP``, the name of its op, and ``draw(rng, corpus, n, traffic)``,
+``n`` distinct query texts; for an op that is not built in it also gives
+the op's ``tokens``, ``send`` and ``answer`` (``bench/ops.py``).
 """
 from __future__ import annotations
 
@@ -28,6 +32,7 @@ import re
 
 import numpy as np
 
+from . import byname, ops
 from .corpus import Corpus, random_ids
 from .reference import ALNUM, line_terms
 
@@ -107,15 +112,38 @@ def draw(scenario: str, rng, corpus: Corpus, n: int, traffic: dict
     raise ValueError(f"unknown scenario {scenario!r}")
 
 
-def pool(traffic: dict, corpus: Corpus, seed: int) -> list[tuple]:
+def module(scenario: str):
+    """The module of a scenario this file does not define."""
+    return byname.load("queries", scenario)
+
+
+def module_files(traffic: dict) -> list[str]:
+    """The files of the scenario modules the traffic's pool names."""
+    return [byname.path("queries", s) for s in traffic["pool"]
+            if s not in OPS]
+
+
+def pool(traffic: dict, corpus: Corpus, seed: int
+         ) -> tuple[list[tuple], dict[str, ops.Op]]:
     """Every distinct query of the traffic's ``pool``, scenario by
-    scenario in the file's order."""
-    rng = np.random.default_rng([seed, 1])
+    scenario in the file's order, and the ops they use by name.  The
+    texts are drawn from ``seed``, or from the traffic's ``pool_seed``
+    where it fixes one pool for every seed."""
+    rng = np.random.default_rng([traffic.get("pool_seed", seed), 1])
     queries: list[tuple] = []
+    table: dict[str, ops.Op] = {}
     for scenario, n in traffic["pool"].items():
-        texts = draw(scenario, rng, corpus, int(n), traffic)
+        if scenario in OPS:
+            op = OPS[scenario]
+            table[op] = ops.BUILTIN[op]
+            texts = draw(scenario, rng, corpus, int(n), traffic)
+        else:
+            mod = module(scenario)
+            op = mod.OP
+            table[op] = ops.of_module(mod)
+            texts = mod.draw(rng, corpus, int(n), traffic)
         if len(texts) < int(n):
             raise ValueError(f"{scenario}: {len(texts)} distinct queries, "
                              f"the traffic asks for {n}")
-        queries += [(scenario, OPS[scenario], t) for t in texts]
-    return queries
+        queries += [(scenario, op, t) for t in texts]
+    return queries, table

@@ -3,14 +3,18 @@
 The store holds the configuration's corpus, which its ``corpus.seed``
 fixes, and is built through the program's own durable path under
 ``bench/.cache/<config>/store-<key>``.  The key covers the
-configuration's corpus and store settings, the generator
-(``bench/corpus.py``) and every source file of the program, so a change
-to any of them builds anew; every other run only reopens the store with
+configuration's corpus and store settings, the generator file it uses
+(``bench/corpora/<corpus.generator>.py``, or ``bench/corpus.py`` where
+it names none) and every source file of the program, so a change to any
+of them builds anew; every other run only reopens the store with
 ``DynaWarpStore.open``, which is what a restart costs.
 
-Reference answers depend on the lines and on the reference alone: they
-are kept per traffic and ``--seed`` under ``answers-<key>``, whose key
-covers the corpus settings, the generator and ``bench/reference.py``.
+Reference answers depend on the lines and on the references alone:
+they are kept per traffic and ``--seed`` under
+``answers/<traffic>/<key>``, whose key covers the corpus settings, the
+generator file, ``bench/reference.py``, ``bench/ops.py`` and the query
+modules under ``bench/queries/`` that the traffic's pool names (the
+references of the ops they bring).
 """
 from __future__ import annotations
 
@@ -20,6 +24,8 @@ import os
 import shutil
 
 import numpy as np
+
+from . import byname
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -44,21 +50,31 @@ def src_hash(src_dir: str = os.path.join(ROOT, "src")) -> str:
     return h.hexdigest()[:16]
 
 
-def _key(settings: dict, bench_files: list[str], extra: str = "") -> str:
+def _key(settings: dict, files: list[str], extra: str = "") -> str:
     h = hashlib.sha256(json.dumps(settings, sort_keys=True).encode())
-    for name in bench_files:
-        h.update(_file_digest(os.path.join(BENCH_DIR, name)))
+    for path in files:
+        h.update(_file_digest(path))
     h.update(extra.encode())
     return h.hexdigest()[:16]
 
 
+def generator_file(cfg: dict) -> str:
+    """The file of the configuration's corpus generator."""
+    name = cfg["corpus"].get("generator")
+    return (byname.path("corpora", name) if name
+            else os.path.join(BENCH_DIR, "corpus.py"))
+
+
 def store_key(cfg: dict) -> str:
     return _key({"corpus": cfg["corpus"], "store": cfg["store"]},
-                ["corpus.py"], src_hash())
+                [generator_file(cfg)], src_hash())
 
 
-def answers_key(cfg: dict) -> str:
-    return _key({"corpus": cfg["corpus"]}, ["corpus.py", "reference.py"])
+def answers_key(cfg: dict, query_files: list[str] = ()) -> str:
+    return _key({"corpus": cfg["corpus"]},
+                [generator_file(cfg)] + [os.path.join(BENCH_DIR, f) for f in
+                                         ("reference.py", "ops.py")]
+                + sorted(query_files))
 
 
 def _config_dir(config: str) -> str:
@@ -101,14 +117,16 @@ def stored_bytes(path: str) -> int:
                for f in os.listdir(path) if f != "built.json")
 
 
-def _answers_file(config: str, cfg: dict, traffic: str, seed: int) -> str:
-    return os.path.join(_config_dir(config), f"answers-{answers_key(cfg)}",
-                        f"{traffic}-seed-{seed}.npz")
+def _answers_file(config: str, cfg: dict, traffic: str, seed: int,
+                  query_files: list[str]) -> str:
+    return os.path.join(_config_dir(config), "answers", traffic,
+                        answers_key(cfg, query_files), f"seed-{seed}.npz")
 
 
-def load_answers(config: str, cfg: dict, traffic: str, seed: int) -> dict:
+def load_answers(config: str, cfg: dict, traffic: str, seed: int,
+                 query_files: list[str]) -> dict:
     """Cached reference answers, ``{(op, text): ids}``."""
-    path = _answers_file(config, cfg, traffic, seed)
+    path = _answers_file(config, cfg, traffic, seed, query_files)
     if not os.path.exists(path):
         return {}
     with np.load(path) as z:
@@ -119,16 +137,16 @@ def load_answers(config: str, cfg: dict, traffic: str, seed: int) -> dict:
 
 
 def save_answers(config: str, cfg: dict, traffic: str, seed: int,
-                 answers: dict) -> None:
+                 query_files: list[str], answers: dict) -> None:
     keys = sorted(answers)
     sizes = [len(answers[k]) for k in keys]
     offsets = np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64)
     ids = (np.concatenate([np.asarray(answers[k], np.int64) for k in keys])
            if keys else np.empty(0, np.int64))
-    path = _answers_file(config, cfg, traffic, seed)
+    path = _answers_file(config, cfg, traffic, seed, query_files)
     adir = os.path.dirname(path)
     os.makedirs(adir, exist_ok=True)
-    _drop_others(os.path.dirname(adir), "answers-", os.path.basename(adir))
+    _drop_others(os.path.dirname(adir), "", os.path.basename(adir))
     np.savez(path + ".tmp.npz", keys=json.dumps([list(k) for k in keys]),
              offsets=offsets, ids=ids)
     os.replace(path + ".tmp.npz", path)

@@ -5,7 +5,10 @@
 Runs the mix once per rate, in one process, on the chip, and prints one
 ``SWEEP`` line per rate and a ``KNEE`` line.  The knee is the highest rate
 whose answered rate stays within 2% of the offered one, with every answer
-correct; a cell's fixed ``rate_qps`` is set at about four fifths of it.
+correct; a cell's fixed ``rate_qps`` is set at about four fifths of it
+where a tail judges the cell, and five fourths where the answers
+completed per second do.  A knee at the highest rate tried is no knee:
+sweep higher.
 The mix need not be a cell of ``BENCHMARK.json`` yet: the sweep is how its
 rate is found before it becomes one.  The benchmark's own runs never
 sweep.

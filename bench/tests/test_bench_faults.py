@@ -104,3 +104,8 @@ def test_half_of_each_wave_left_out_is_not_correct(monkeypatch):
 def test_answer_altered_where_produced_is_not_correct():
     out = _run("loghub-1m.hunt", fault=_drop_last_match)
     assert not out["correct"]
+
+
+def test_ioc_answer_altered_where_produced_is_not_correct():
+    out = _run("loghub-1m.ioc", fault=_drop_last_match)
+    assert not out["correct"]

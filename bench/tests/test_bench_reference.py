@@ -53,7 +53,7 @@ def test_reference_equals_served_answers(tiny_store):
                         "present_term_ip": 4, "contains_ip": 4,
                         "contains_id": 4, "term_extracted": 4},
                "small_sources": 12, "extracted_lines": 150}
-    queries = scenarios.pool(traffic, c, seed=3)
+    queries, _ = scenarios.pool(traffic, c, seed=3)
     terms = [t for _, op, t in queries if op == "term"]
     needles = [t for _, op, t in queries if op == "contains"]
     term_ans, contains_ans = reference.answer(c.lines, terms, needles)

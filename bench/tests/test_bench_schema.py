@@ -100,6 +100,36 @@ def test_every_cell_reports_enough(bench):
                    for s in (m["name"], stem)), m["name"]
 
 
+def _load(kind, name):
+    with open(os.path.join(ROOT, "bench", kind, f"{name}.json")) as f:
+        return json.load(f)
+
+
+def test_generators_and_scenarios_are_found_by_name(bench):
+    """Each config's corpus generator and each traffic's scenarios exist
+    as the harness looks them up, with the names the harness calls."""
+    import inspect
+
+    from bench import byname, ops, scenarios
+    from repro.logstore.store import DynaWarpStore
+    open_keys = set(inspect.signature(DynaWarpStore.open).parameters)
+    for c in bench["configs"]:
+        cfg = _load("configs", c["name"])
+        gen = cfg["corpus"].get("generator")
+        if gen is not None:
+            assert callable(byname.load("corpora", gen).generate), gen
+        assert set(cfg.get("open", {})) <= open_keys - {"cls", "path"}
+    for traffic in {w["traffic"] for w in bench["workloads"]}:
+        t = _load("traffic", traffic)
+        for scenario in t["pool"]:
+            if scenario not in scenarios.OPS:
+                mod = scenarios.module(scenario)
+                assert isinstance(mod.OP, str) and callable(mod.draw)
+                op = ops.of_module(mod)
+                assert callable(op.tokens) and callable(op.send), scenario
+                assert op.answer is None or callable(op.answer), scenario
+
+
 def test_full_check_fits_at_24_cells(bench):
     runs = 2 + 14 * 24
     need = runs * (bench["run_seconds"] + 60) + 24 * 2 * 90 + 1200
